@@ -398,7 +398,9 @@ class DegradationLadder:
     event) or re-raises when the floor is reached.  Only
     ``RuntimeError`` (XLA runtime failures, ``WaveFailure``, injected
     ``FaultInjected``) is retryable — ``ValueError``/``TypeError`` are
-    caller bugs and propagate untouched.
+    caller bugs and propagate untouched, and so does a device program
+    the compiler refuses (``DeviceProgramError``, raised before the wave
+    launches): no rung below answers in its place.
     """
 
     def __init__(

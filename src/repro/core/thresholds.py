@@ -100,6 +100,11 @@ def optimize_threshold_sorted(
     if n_exited < g.shape[0]:
         first_out = g_sorted[n_exited]
         thr = 0.5 * (last_in + first_out)
+        if thr == last_in:
+            # adjacent floats: the midpoint rounds onto the last exiting
+            # value, where the strict test would drop it; the first
+            # staying value is then the only cut that separates them
+            thr = first_out
     else:
         # everything exits: any threshold beyond the extreme value works.
         thr = last_in + 1.0 if side == "neg" else last_in - 1.0

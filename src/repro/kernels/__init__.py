@@ -9,7 +9,10 @@ sharded_executor: that program shard_map'd over a mesh's "data" axis —
                  data-parallel serving with per-shard survivor buffers
                  (DESIGN.md §6).
 
-All validated against pure-jnp oracles in ``ref.py`` via interpret=True.
+interpret:       per-call choice of Mosaic (TPU) or interpret mode (CPU).
+
+All validated against pure-jnp oracles in ``ref.py``: in interpret mode on
+the CPU by the tests, compiled for a v5e by ``tests/test_tpu_compile.py``.
 """
 
 from repro.kernels import device_executor, ops, ref

@@ -76,7 +76,8 @@ from repro.kernels.cascade_kernel import (
     cascade_group_pallas,
     cascade_lane_pallas,
 )
-from repro.kernels.lattice_kernel import lattice_scores_pallas
+from repro.kernels.interpret import resolve_interpret
+from repro.kernels.lattice_kernel import halving_sum, lattice_scores_pallas
 from repro.kernels.tree_kernel import gbt_scores_pallas
 from repro.testing import faults
 
@@ -88,6 +89,43 @@ class WaveFailure(RuntimeError):
     type, so the degradation ladder has a single retryable signal.
     Shape/argument errors (``ValueError``/``TypeError``) pass through
     untouched: those are caller bugs, not transient faults."""
+
+
+class DeviceProgramError(Exception):
+    """The compiler refused a device program: lowering or compiling it
+    failed.  Deliberately NOT a ``RuntimeError``: the degradation ladder
+    retries and falls only on runtime faults, so a program that cannot
+    be built for its device stops the caller instead of being answered
+    quietly by a lower rung."""
+
+
+def _signature(a):
+    if hasattr(a, "shape") and hasattr(a, "dtype"):
+        return (tuple(a.shape), str(a.dtype))
+    return type(a).__name__
+
+
+def compile_program(compiled: set, jitted, *args, static: int = 0) -> None:
+    """Lower and compile ``jitted`` for ``args`` OUTSIDE the wave fault
+    contract, once per argument signature (the first ``static`` args are
+    static and keyed by value).  Errors propagate: a ``ValueError`` /
+    ``TypeError`` as it is, anything else as ``DeviceProgramError``
+    chained to it.  The launch that follows inside ``launch_wave`` reuses
+    the executable jit cached here, so only runtime faults reach it."""
+    key = tuple(
+        a if i < static else _signature(a) for i, a in enumerate(args)
+    )
+    if key in compiled:
+        return
+    try:
+        jitted.lower(*args).compile()
+    except (ValueError, TypeError):
+        raise
+    except Exception as e:
+        raise DeviceProgramError(
+            f"device program failed to compile: {type(e).__name__}: {e}"
+        ) from e
+    compiled.add(key)
 
 
 def launch_wave(executor_name: str, fn):
@@ -129,6 +167,7 @@ def check_batch_finite(batch, n: int) -> None:
         )
 
 __all__ = [
+    "DeviceProgramError",
     "DevicePlan",
     "BoundScorer",
     "StreamResult",
@@ -141,9 +180,6 @@ __all__ = [
     "lattice_stage_scorer",
     "stream_occupancy",
 ]
-
-# Mirrors repro.kernels.ops.INTERPRET (not imported: ops imports us).
-INTERPRET = jax.default_backend() != "tpu"
 
 DEFAULT_BLOCK_N = 64
 
@@ -395,7 +431,6 @@ def tree_stage_scorer(
     (inert even before the executor masks their columns).  ``quant``
     overrides the plan's slab storage dtype for the megakernel path."""
     W, T_pad = dplan.W, dplan.T_pad
-    it = INTERPRET if interpret is None else interpret
     T, depth = np.asarray(feats_ordered).shape
     n_leaves = np.asarray(leaves_ordered).shape[1]
     slabs = mk.build_tree_slabs(
@@ -415,7 +450,7 @@ def tree_stage_scorer(
         th = jax.lax.dynamic_slice(thrs_p, (t0, 0), (W, depth))
         lv = jax.lax.dynamic_slice(leaves_p, (t0, 0), (W, n_leaves))
         return gbt_scores_pallas(
-            f, th, lv, x, block_n=block_n, interpret=it, rows=rows,
+            f, th, lv, x, block_n=block_n, interpret=interpret, rows=rows,
             n_valid=n_valid,
         )
 
@@ -453,7 +488,7 @@ def tree_stage_scorer(
             th = jax.lax.dynamic_index_in_dim(mp["thrs"], s, 0, keepdims=False)
             lv = jax.lax.dynamic_index_in_dim(mp["leaves"], s, 0, keepdims=False)
             return gbt_scores_pallas(
-                f, th, lv, x, block_n=block_n, interpret=it, rows=rows,
+                f, th, lv, x, block_n=block_n, interpret=interpret, rows=rows,
                 n_valid=n_valid,
             )
 
@@ -476,7 +511,6 @@ def lattice_stage_scorer(
     """Lattice scorer: same slab scheme as ``tree_stage_scorer`` over the
     cascade-ordered (theta, feats) stacks."""
     W, T_pad = dplan.W, dplan.T_pad
-    it = INTERPRET if interpret is None else interpret
     T, S_feats = np.asarray(feats_ordered).shape
     p = np.asarray(theta_ordered).shape[1]
     slabs = mk.build_lattice_slabs(
@@ -492,13 +526,14 @@ def lattice_stage_scorer(
         th = jax.lax.dynamic_slice(theta_p, (t0, 0), (W, p))
         f = jax.lax.dynamic_slice(feats_p, (t0, 0), (W, S_feats))
         return lattice_scores_pallas(
-            th, f, x, block_n=block_n, interpret=it, rows=rows,
+            th, f, x, block_n=block_n, interpret=interpret, rows=rows,
             n_valid=n_valid,
         )
 
     def lane_fn(x, rows, t0_lane, n_valid):
-        # per-lane slab gather + the kernel's interleaved-doubling corner
-        # weights, finished with the same (2**S,) contraction per lane
+        # per-lane slab gather + interleaved-doubling corner weights (the
+        # kernel's products in the kernel's order), finished with the
+        # same (2**S,) contraction per lane
         xr = jnp.take(x, rows, axis=0)  # (cap, d)
         cap = xr.shape[0]
         pos = t0_lane[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
@@ -510,10 +545,9 @@ def lattice_stage_scorer(
             w = jnp.stack([w * (1.0 - xj), w * xj], axis=-1).reshape(
                 cap, W, -1
             )
-        # elementwise-sum contraction (NOT einsum/dot): the same
-        # accumulation order the megakernel's lane variant uses, keeping
-        # the f32 streaming paths bit-identical to each other
-        return jnp.sum(w * th, axis=-1)
+        # halving-sum contraction (NOT einsum/dot): the pairwise order of
+        # the lattice kernels, keeping every f32 path bit-identical
+        return halving_sum(w * th)[..., 0]
 
     def model_partition(model_shards: int):
         from repro.launch.shardings import split_columns, stage_column_slices
@@ -529,7 +563,7 @@ def lattice_stage_scorer(
             th = jax.lax.dynamic_index_in_dim(mp["theta"], s, 0, keepdims=False)
             f = jax.lax.dynamic_index_in_dim(mp["feats"], s, 0, keepdims=False)
             return lattice_scores_pallas(
-                th, f, x, block_n=block_n, interpret=it, rows=rows,
+                th, f, x, block_n=block_n, interpret=interpret, rows=rows,
                 n_valid=n_valid,
             )
 
@@ -703,9 +737,8 @@ class DeviceExecutor:
     this and wall-clock.
 
     ``megakernel`` selects the fused stage-step path (DESIGN.md §9): one
-    Pallas kernel per stage does slab gather + scoring + threshold decide
-    + the block-local compaction prefix, instead of the score kernel /
-    decide kernel / cap-wide cumsum sequence.  ``None`` (default) auto-
+    Pallas kernel per stage does slab gather + scoring + threshold decide,
+    instead of the score kernel / decide kernel sequence.  ``None`` (default) auto-
     enables it when the scorer carries f32 ``ParamSlabs`` — bit-identical
     results AND billing, so it is the default device scorer path for
     factory-built scorers; quantized (bf16/int8) slabs must be requested
@@ -747,8 +780,9 @@ class DeviceExecutor:
         self.scorer = scorer
         self.check_finite = bool(check_finite)
         self.block_n = max(1, int(block_n))
-        self.interpret = INTERPRET if interpret is None else interpret
+        self.interpret = resolve_interpret(interpret)
         self.traces = 0
+        self._compiled: set = set()  # argument signatures compiled so far
         self._jit = jax.jit(self._program)
         self._stream_jit = jax.jit(self._stream_program, static_argnums=(0,))
         # grouped (ranking) programs: k is static — verdict extraction
@@ -806,9 +840,9 @@ class DeviceExecutor:
             g_rows = jnp.take(g, rows, axis=0)  # trash indices clamp
             if self.megakernel:
                 # ONE fused kernel: slab select by prefetched stage,
-                # score + decide + block-local compaction prefix — the
-                # survivor buffer makes one round trip, and the pack
-                # positions come back ready to scatter (DESIGN.md §9)
+                # score + decide — the survivor buffer makes one round
+                # trip, and the pack positions come back ready to
+                # scatter (DESIGN.md §9)
                 xr = jnp.take(x, rows, axis=0)  # trash indices clamp
                 g_new, active, dpos, ex_rel, pack, n_keep = (
                     mk.mega_stage_pallas(
@@ -938,8 +972,10 @@ class DeviceExecutor:
         assert rows.shape == (n,)
         rows_init = np.full(cap, cap, dtype=np.int32)
         rows_init[:n] = rows
+        args = (x, jnp.asarray(rows_init), n)
+        compile_program(self._compiled, self._jit, *args)
         dec, ex, g, s_f, n_f, n_in_log = launch_wave(
-            "device", lambda: self._jit(x, jnp.asarray(rows_init), n)
+            "device", lambda: self._jit(*args)
         )
         dec = np.asarray(dec)[:n]
         ex = np.asarray(ex, dtype=np.int64)[:n]
@@ -1195,11 +1231,10 @@ class DeviceExecutor:
         assert (np.diff(arr) >= 0).all(), "arrivals must be nondecreasing"
         arr_pad = np.zeros(R, dtype=np.int32)
         arr_pad[:n] = arr
+        args = (cap, x, jnp.asarray(ring_ids), jnp.asarray(arr_pad), n)
+        compile_program(self._compiled, self._stream_jit, *args, static=1)
         dec, ex, gout, admit, done, s_f = launch_wave(
-            "device",
-            lambda: self._stream_jit(
-                cap, x, jnp.asarray(ring_ids), jnp.asarray(arr_pad), n
-            ),
+            "device", lambda: self._stream_jit(*args)
         )
         steps_run = int(s_f)
         admit = np.asarray(admit, dtype=np.int64)[:n]
@@ -1414,17 +1449,18 @@ class DeviceExecutor:
         rows_init[:n_groups] = group_rows[:n_groups]
         valid_init = np.zeros((cap_g, B), dtype=np.int32)
         valid_init[:n_groups] = group_valid[:n_groups].astype(np.int32)
+        args = (
+            int(k),
+            x,
+            jnp.asarray(gids),
+            jnp.asarray(rows_init),
+            jnp.asarray(valid_init),
+            n_groups,
+            jnp.asarray(eps_g, dtype=jnp.float32),
+        )
+        compile_program(self._compiled, self._grouped_jit, *args, static=1)
         verd, exst, marg, s_f, n_f, n_in_log = launch_wave(
-            "device",
-            lambda: self._grouped_jit(
-                int(k),
-                x,
-                jnp.asarray(gids),
-                jnp.asarray(rows_init),
-                jnp.asarray(valid_init),
-                n_groups,
-                jnp.asarray(eps_g, dtype=jnp.float32),
-            ),
+            "device", lambda: self._grouped_jit(*args)
         )
         s_f, n_f = int(s_f), int(n_f)
         n_in_log = np.asarray(n_in_log)
@@ -1658,19 +1694,20 @@ class DeviceExecutor:
         assert (np.diff(arr) >= 0).all(), "arrivals must be nondecreasing"
         arr_pad = np.zeros(Rg, dtype=np.int32)
         arr_pad[:n_groups] = arr
+        args = (
+            cap_g,
+            int(k),
+            x,
+            jnp.asarray(ring_gids),
+            jnp.asarray(ring_rows),
+            jnp.asarray(ring_valid),
+            jnp.asarray(arr_pad),
+            n_groups,
+            jnp.asarray(eps_g, dtype=jnp.float32),
+        )
+        compile_program(self._compiled, self._grouped_stream_jit, *args, static=2)
         verd, exst, marg, admit, done, s_f = launch_wave(
-            "device",
-            lambda: self._grouped_stream_jit(
-                cap_g,
-                int(k),
-                x,
-                jnp.asarray(ring_gids),
-                jnp.asarray(ring_rows),
-                jnp.asarray(ring_valid),
-                jnp.asarray(arr_pad),
-                n_groups,
-                jnp.asarray(eps_g, dtype=jnp.float32),
-            ),
+            "device", lambda: self._grouped_stream_jit(*args)
         )
         steps_run = int(s_f)
         admit = np.asarray(admit, dtype=np.int64)[:n_groups]

@@ -3,17 +3,17 @@
 The paper's real-world ensembles are lattices — interpolated look-up tables.
 A lattice over S features evaluates as a contraction of its (2,)*S parameter
 tensor with per-dimension [1-x_j, x_j] vectors.  The TPU-native formulation
-used here builds the (block_n, 2**S) corner-weight matrix by S successive
-interleaved doublings in VMEM (pure VPU) and finishes with a single
-(block_n, 2**S) @ (2**S,) contraction — an MXU matmul when batched — instead
-of the gather-heavy GPU formulation.
+used here builds the (block_n, 2**S) corner-weight matrix in VMEM (pure
+VPU): corner c's weight is the product, over features j in order, of x_j
+or 1 - x_j as bit j of c (MSB-first) says — the same products, in the same
+order, as S interleaved doublings — and finishes with an elementwise
+(block_n, 2**S) * theta product summed by repeated halving (a fixed
+pairwise order, so XLA and Mosaic, CPU and TPU all produce the same bits)
+instead of the gather-heavy GPU formulation.
 
-Feature subsets are per-lattice dynamic column indices into x: they ride in
-as scalar-prefetch arguments so the index math is resolved before the body
-runs (pltpu.PrefetchScalarGridSpec).
-
-Grid: (T, ceil(N / block_n)).  x block (block_n, D) re-used across the T
-axis; theta block (1, 2**S); out block (1, block_n) of the (T, N) output.
+Feature subsets are per-lattice column ids; like the tree kernel they
+arrive as one-hot (S, D) masks built outside the kernel.  Grid, blocking
+and the live-count guard are the tree kernel's (``model_block_call``).
 """
 
 from __future__ import annotations
@@ -23,46 +23,73 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.interpret import resolve_interpret
+from repro.kernels.tree_kernel import (
+    feature_masks,
+    model_block_call,
+    select_feature,
+)
 
 DEFAULT_BLOCK_N = 256
 
-__all__ = ["lattice_scores_pallas"]
+__all__ = ["lattice_scores_pallas", "lattice_column", "halving_sum"]
 
 
-def _lattice_kernel(feats_ref, nv_ref, x_ref, theta_ref, out_ref, *, S: int, t0: int):
-    t = t0 + pl.program_id(0)  # absolute lattice index within the model range
-    bn = x_ref.shape[0]
-    block_start = pl.program_id(1) * bn
+def halving_sum(v):
+    """Sum over the last (power-of-two) axis by repeated halving, keeping
+    it as a length-1 axis.  The pairwise order is spelled out, so every
+    backend — XLA or Mosaic, CPU or TPU — adds the same pairs."""
+    while v.shape[-1] > 1:
+        h = v.shape[-1] // 2
+        v = v[..., :h] + v[..., h:]
+    return v
+
+
+def lattice_column(x, masks, theta):
+    """One lattice for every row of ``x`` (n, D) -> (n, 1) scores.
+
+    ``masks[j]`` is feature j's (1 or n, D) column mask and ``theta`` the
+    (1 or n, 2**S) parameter row (shared or per lane, as in
+    ``tree_column``)."""
+    S = len(masks)
+    corner = jax.lax.broadcasted_iota(jnp.int32, (1, theta.shape[1]), 1)
+    w = jnp.ones((x.shape[0], theta.shape[1]), x.dtype)
+    for j, mask in enumerate(masks):
+        xj = select_feature(x, mask)
+        bit = ((corner >> (S - 1 - j)) & 1) != 0
+        w = w * jnp.where(bit, xj, 1.0 - xj)
+    return halving_sum(w * theta)
+
+
+def _lattice_kernel(nv_ref, x_ref, fm_ref, theta_ref, out_ref, *, S: int):
+    bn, tc = out_ref.shape
+    block_start = pl.program_id(0) * bn
 
     # live-count block guard (DESIGN.md §5): blocks past the compacted
     # live rows skip the interpolation and emit zeros.
     @pl.when(block_start >= nv_ref[0])
     def _skip():
-        out_ref[0, :] = jnp.zeros((bn,), dtype=out_ref.dtype)
+        out_ref[...] = jnp.zeros((bn, tc), dtype=out_ref.dtype)
 
     @pl.when(block_start < nv_ref[0])
     def _eval():
-        w = jnp.ones((bn, 1), dtype=x_ref.dtype)
-        for j in range(S):
-            f = feats_ref[t, j]
-            xj = pl.load(x_ref, (slice(None), pl.dslice(f, 1)))  # (bn, 1)
-            # interleaved doubling keeps bit j of the corner index MSB-first,
-            # matching theta's reshape((2,)*S) layout.
-            w = jnp.stack([w * (1.0 - xj), w * xj], axis=-1).reshape(bn, -1)
-        theta = theta_ref[0, :]  # (2**S,)
-        out_ref[0, :] = w @ theta
+        x, fm, theta = x_ref[...], fm_ref[...], theta_ref[...]
+        for t in range(tc):
+            r = t * S
+            out_ref[:, t:t + 1] = lattice_column(
+                x,
+                [fm[r + j:r + j + 1, :] != 0 for j in range(S)],
+                theta[t:t + 1, :],
+            )
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret", "t0", "t1")
-)
 def lattice_scores_pallas(
     theta: jax.Array,
     feats: jax.Array,
     x: jax.Array,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool | None = None,
     t0: int = 0,
     t1: int | None = None,
     rows: jax.Array | None = None,
@@ -78,40 +105,33 @@ def lattice_scores_pallas(
     DESIGN.md §4.  ``n_valid`` (traced scalar) makes row-blocks past the
     live count skip compute and emit zeros — the device executor's
     fixed-capacity hook (DESIGN.md §5).  Defaults preserve the eager
-    full-matrix behaviour.
+    full-matrix behaviour.  ``interpret=None`` runs compiled on an
+    accelerator and interpreted where ``x`` lives on the CPU.
     """
+    return _lattice_scores(
+        theta, feats, x, rows, n_valid, block_n=block_n,
+        interpret=resolve_interpret(interpret, x), t0=t0, t1=t1,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_n", "interpret", "t0", "t1")
+)
+def _lattice_scores(theta, feats, x, rows, n_valid, *, block_n, interpret,
+                    t0, t1):
     T, p = theta.shape
     S = feats.shape[1]
     assert p == 1 << S
     if t1 is None:
         t1 = T
     assert 0 <= t0 < t1 <= T
-    tk = t1 - t0
     if rows is not None:
         x = jnp.take(x, jnp.asarray(rows, dtype=jnp.int32), axis=0)
-    n, d = x.shape
-    n_pad = -n % block_n
-    if n_pad:
-        x = jnp.pad(x, ((0, n_pad), (0, 0)))
-    np_total = x.shape[0]
-    nv = jnp.full(
-        (1,),
-        np_total if n_valid is None else n_valid,
-        dtype=jnp.int32,
+    feats = feats[t0:t1].astype(jnp.int32)
+    return model_block_call(
+        functools.partial(_lattice_kernel, S=S),
+        x,
+        [theta[t0:t1].astype(x.dtype)],
+        feature_masks(feats, x.shape[1]),
+        block_n=block_n, n_valid=n_valid, dtype=x.dtype, interpret=interpret,
     )
-    grid = (tk, np_total // block_n)
-    out = pl.pallas_call(
-        functools.partial(_lattice_kernel, S=S, t0=t0),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_n, d), lambda t, i, feats, nv: (i, 0)),
-                pl.BlockSpec((1, p), lambda t, i, feats, nv: (t0 + t, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, block_n), lambda t, i, feats, nv: (t, i)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((tk, np_total), x.dtype),
-        interpret=interpret,
-    )(feats.astype(jnp.int32), nv, x, theta.astype(x.dtype))
-    return out[:, :n].T

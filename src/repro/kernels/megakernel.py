@@ -15,16 +15,14 @@ block:
   prefetched stage VALUE.  Pallas's pipeline machinery multiple-buffers
   BlockSpec blocks, so the next block's slab DMA overlaps the current
   block's compute (the double-buffered slab prefetch).
-* **score + decide + prefix in VMEM.**  Inside the kernel the W base
-  models of the stage are walked unrolled: variant-specific scoring
-  (matrix column read at a dynamic ``t0`` offset, oblivious-tree
-  compare/descend/leaf-select, lattice interleaved-doubling corner
-  weights) feeds straight into the shared ``threshold_step`` semantics
-  from ``cascade_kernel`` — the same single source of truth every other
-  decide uses.  The block-local compaction prefix (``cumsum(keep) - 1``)
-  and the block's survivor count are emitted as two extra outputs, so
-  the executor's pack positions come from a tiny (n_blocks,) exclusive
-  scan instead of a cap-wide cumsum.
+* **score + decide in VMEM.**  Inside the kernel the W base models of
+  the stage are walked unrolled: variant-specific scoring (matrix
+  column select at the stage's ``t0`` offset, and the tree / lattice
+  kernels' own ``tree_column`` / ``lattice_column``) feeds straight
+  into the shared ``threshold_step`` semantics from ``cascade_kernel``
+  — the same single source of truth every other decide uses.  The
+  compaction prefix over the surviving lanes is one cumsum outside the
+  kernel (Mosaic has no in-kernel cumsum).
 * **quantized param slabs.**  ``ParamSlabs`` stores the cascade-ordered
   per-stage parameter stacks at ``f32``, ``bf16`` (the default for
   quantized storage) or ``int8`` (per-slab scale, one f32 scalar per
@@ -68,6 +66,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.cascade_kernel import threshold_step
+from repro.kernels.interpret import resolve_interpret
+from repro.kernels.lattice_kernel import lattice_column
+from repro.kernels.tree_kernel import feature_masks, select_feature, tree_column
 
 __all__ = [
     "ParamSlabs",
@@ -366,191 +367,195 @@ def check_parity(oracle, result, eps_position, g_scale: float = 1.0) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# in-kernel scoring helpers (shared by the batch and lane kernels)
+# in-kernel scoring (shared by the batch and lane kernels)
 # ---------------------------------------------------------------------------
 
 
-def _onehot_gather(x, idx, width):
-    """Per-lane dynamic gather ``x[i, idx[i]]`` as a one-hot contraction
-    — the vector-friendly form of a row-wise dynamic index, exact
-    because the one-hot mask selects (never scales) values."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], width), 1)
-    return jnp.sum(jnp.where(cols == idx[:, None], x, 0.0), axis=1)
+def _stage_scorer(variant, quant, x, params, scale, t0, *, lane_mode,
+                  levels, width):
+    """``score_j(j) -> (bn, 1)`` f32 scores of the stage's model j.
 
+    Batch mode: every lane of the block runs the same stage, so params
+    are stage blocks with one row per model — (W * levels, D) one-hot
+    feature masks, (W, levels) thresholds, (W, width) payload — and
+    ``scale`` is the stage's (1, 1) dequantization scale.  Lane mode:
+    each lane carries its own stage's params, flattened to (bn, W *
+    levels) feature ids / thresholds and a (bn, W * width) payload, with
+    a (bn, 1) scale.  ``levels`` is the tree depth or the lattice's
+    feature count, ``width`` the leaf-table or theta width.  Scoring goes
+    through ``tree_column`` / ``lattice_column`` — the functions
+    ``gbt_scores_pallas`` / ``lattice_scores_pallas`` use — so the fused
+    and multi-kernel paths compute every score with the same operations.
+    """
+    if variant == "matrix":
+        (widths,) = params
+        x = x.astype(jnp.float32)
+        if lane_mode:  # x is each lane's own (bn, W) stage slab
 
-def _tree_score_stage(x_ref, feats, thrs, leaves, scale, j, quant, lane_mode):
-    """Score model j of the stage for every lane: compare/descend the
-    oblivious tree MSB-first, then select the leaf via a one-hot
-    contraction (bit-identical to ``gbt_scores_pallas``'s onehot @ LUT —
-    same comparisons at the same dtype, same leaf)."""
-    bn = x_ref.shape[0]
-    depth = feats.shape[-1]
-    n_leaves = leaves.shape[-1]
-    idx = jnp.zeros((bn,), jnp.int32)
-    for k in range(depth):
-        if lane_mode:
-            f = feats[:, j, k]  # (bn,) per-lane feature ids
-            xj = _onehot_gather(x_ref[...], f, x_ref.shape[1])
-            bit = xj > thrs[:, j, k]
-        else:
-            f = feats[0, j, k]  # stage-shared scalar feature id
-            xj = pl.load(x_ref, (slice(None), pl.dslice(f, 1)))[:, 0]
-            bit = xj > thrs[0, j, k]
-        idx = 2 * idx + bit.astype(jnp.int32)
-    lv = (leaves[:, j, :] if lane_mode else leaves[0, j, :]).astype(
-        jnp.float32
-    )
-    if quant == "int8":
-        lv = lv * (scale if lane_mode else scale[0, 0])
+            def score_j(j):
+                return jnp.where(j < widths, x[:, j:j + 1], 0.0)
+        else:  # x is the whole (bn, T_pad) operand: column t0 + j
+            cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+
+            def score_j(j):
+                col = select_feature(x, cols == t0 + j)
+                return jnp.where(j < widths, col, 0.0)
+
+        return score_j
+
+    feats, payload = params[0], params[-1]
     if lane_mode:
-        return _onehot_gather(lv, idx, n_leaves)
-    onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, (bn, n_leaves), 1) == idx[:, None]
-    ).astype(jnp.float32)
-    return onehot @ lv
+        cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+
+        def masks(j):
+            r = j * levels
+            return [cols == feats[:, r + k:r + k + 1] for k in range(levels)]
+
+        def row(a, j, w):
+            return a[:, j * w:(j + 1) * w]
+    else:
+
+        def masks(j):
+            r = j * levels
+            return [feats[r + k:r + k + 1, :] != 0 for k in range(levels)]
+
+        def row(a, j, w):
+            return a[j:j + 1, :]
+
+    def payload_row(j):
+        p = row(payload, j, width).astype(jnp.float32)
+        return p * scale if quant == "int8" else p
+
+    if variant == "tree":
+        thrs = params[1]
+
+        def score_j(j):
+            th = row(thrs, j, levels)
+            return tree_column(
+                x, masks(j), [th[:, k:k + 1] for k in range(levels)],
+                payload_row(j),
+            )
+    else:  # lattice
+
+        def score_j(j):
+            return lattice_column(x, masks(j), payload_row(j))
+
+    return score_j
 
 
-def _lattice_score_stage(x_ref, feats, theta, scale, j, quant, lane_mode):
-    """Score model j: interleaved-doubling corner weights (the
-    ``lattice_scores_pallas`` construction) contracted against the
-    dequantized theta row."""
-    bn = x_ref.shape[0]
-    n_feats = feats.shape[-1]
-    w = jnp.ones((bn, 1), jnp.float32)
-    for k in range(n_feats):
-        if lane_mode:
-            f = feats[:, j, k]
-            xj = _onehot_gather(x_ref[...], f, x_ref.shape[1])[:, None]
-        else:
-            f = feats[0, j, k]
-            xj = pl.load(x_ref, (slice(None), pl.dslice(f, 1)))
-        w = jnp.stack([w * (1.0 - xj), w * xj], axis=-1).reshape(bn, -1)
-    th = (theta[:, j, :] if lane_mode else theta[0, j, :]).astype(jnp.float32)
-    if quant == "int8":
-        th = th * (scale if lane_mode else scale[0, 0])
-    if lane_mode:
-        return jnp.sum(w * th, axis=-1)
-    return w @ th
-
-
-def _walk_and_pack(
-    score_j, ep_j, en_j, g0, nv, block_start, W, stop=None
-):
+def _walk(score_j, ep, en, g0, nv, block_start, W):
     """The fused inner step: unrolled threshold walk over the stage's W
-    models (``threshold_step`` semantics, relative 1-based exits), then
-    the block-local compaction prefix over the surviving lanes."""
+    models (``threshold_step`` semantics, relative 1-based exits).
+    ``ep``/``en`` are (1, W) stage rows or (bn, W) per-lane rows."""
     bn = g0.shape[0]
-    lane = block_start + jax.lax.broadcasted_iota(jnp.int32, (bn,), 0)
+    lane = block_start + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
     g = g0.astype(jnp.float32)
     active = lane < nv
-    dec = jnp.zeros((bn,), jnp.bool_)
-    ex = jnp.zeros((bn,), jnp.int32)
+    dec = jnp.zeros((bn, 1), jnp.bool_)
+    ex = jnp.zeros((bn, 1), jnp.int32)
     for j in range(W):
         g, active, dec, ex = threshold_step(
-            g, active, dec, ex, score_j(j), ep_j(j), en_j(j), j + 1
+            g, active, dec, ex, score_j(j), ep[:, j:j + 1], en[:, j:j + 1],
+            j + 1,
         )
-    keep = active if stop is None else active & ~stop
-    pfx = jnp.cumsum(keep.astype(jnp.int32)) - 1
-    return g, active, dec, ex, keep, pfx
+    return g, active, dec, ex
 
 
-def _write_outputs(g_ref, act_ref, dec_ref, ex_ref, pfx_ref, cnt_ref,
-                   g, active, dec, ex, pfx, count):
-    g_ref[...] = g
-    act_ref[...] = active.astype(jnp.int32)
-    dec_ref[...] = dec.astype(jnp.int32)
-    ex_ref[...] = ex
-    pfx_ref[...] = pfx
-    cnt_ref[0] = count
+def _mega_kernel(
+    *refs, variant: str, quant: str, W: int, lane_mode: bool, levels: int,
+    width: int,
+):
+    """One survivor block, one stage step: score the W models, then
+    threshold-decide.  Blocks past the live count write inert outputs and
+    compute nothing — the same block-guard billing semantics as the
+    multi-kernel path's score kernels.
+
+    Batch mode (stage-uniform blocks) takes the scalar-prefetched stage,
+    its first cascade position ``t0`` and the live count, and selects
+    every per-stage operand by the stage VALUE in its BlockSpec index
+    map, so Pallas's pipeline prefetches the next stage slab.  Lane mode
+    (mixed-stage blocks, the streaming refill) takes only the live count:
+    every per-stage quantity arrives pre-gathered per lane."""
+    if lane_mode:
+        (nv_ref, g0_ref, x_ref, *rest) = refs
+        t0 = None
+    else:
+        (_, t0_ref, nv_ref, g0_ref, x_ref, *rest) = refs
+        t0 = t0_ref[0]
+    *param_refs, scale_ref, ep_ref, en_ref, g_ref, act_ref, dec_ref, ex_ref = rest
+    bn = g0_ref.shape[0]
+    block_start = pl.program_id(0) * bn
+    nv = nv_ref[0]
+
+    def write(g, active, dec, ex):
+        g_ref[...] = g
+        act_ref[...] = active.astype(jnp.int32)
+        dec_ref[...] = dec.astype(jnp.int32)
+        ex_ref[...] = ex
+
+    @pl.when(block_start >= nv)
+    def _skip():
+        zero = jnp.zeros((bn, 1), jnp.int32)
+        write(g0_ref[...].astype(jnp.float32), zero, zero, zero)
+
+    @pl.when(block_start < nv)
+    def _compute():
+        score_j = _stage_scorer(
+            variant, quant, x_ref[...], [r[...] for r in param_refs],
+            scale_ref[...], t0, lane_mode=lane_mode, levels=levels,
+            width=width,
+        )
+        write(*_walk(
+            score_j, ep_ref[...], en_ref[...], g0_ref[...], nv, block_start, W
+        ))
+
+
+def _levels_width(slabs: ParamSlabs) -> tuple[int, int]:
+    """(levels, payload width) of a tree/lattice slab; (0, 0) for matrix."""
+    if slabs.variant == "matrix":
+        return 0, 0
+    return int(slabs.data["feats"].shape[-1]), int(slabs.data["payload"].shape[-1])
+
+
+def _launch(slabs, scalars, operands, specs, cap, bn, *, lane_mode,
+            interpret):
+    """Run the fused kernel over ``cap`` lanes in blocks of ``bn`` and
+    return its (g, active, decided_pos, exit_rel) lanes, each (cap,)."""
+    levels, width = _levels_width(slabs)
+    capp = operands[0].shape[0]
+    out_spec = pl.BlockSpec((bn, 1), lambda i, *_: (i, 0))
+    outs = pl.pallas_call(
+        functools.partial(
+            _mega_kernel, variant=slabs.variant, quant=slabs.quant,
+            W=slabs.W, lane_mode=lane_mode, levels=levels, width=width,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(capp // bn,),
+            in_specs=specs,
+            out_specs=[out_spec] * 4,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((capp, 1), jnp.float32)]
+        + [jax.ShapeDtypeStruct((capp, 1), jnp.int32)] * 3,
+        interpret=resolve_interpret(interpret, operands[0]),
+    )(*scalars, *operands)
+    return tuple(o[:cap, 0] for o in outs)
+
+
+def _pack(keep, cap):
+    """Cumsum-prefix compaction of the surviving lanes: each kept lane's
+    front-packed destination, or ``cap`` (out of bounds, dropped)."""
+    pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
+    return jnp.where(keep, pos, cap), keep.sum(dtype=jnp.int32)
+
+
+def _pad_rows(a, pad):
+    return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)) if pad else a
 
 
 # ---------------------------------------------------------------------------
 # the batch megakernel (stage-uniform blocks)
 # ---------------------------------------------------------------------------
-
-
-def _mega_batch_kernel(
-    s_ref, t0_ref, nv_ref,  # scalar prefetch
-    g0_ref, x_ref, *rest,
-    variant: str, quant: str, W: int,
-):
-    """One survivor block, one stage: slab-select by prefetched stage,
-    score W models, threshold-decide, emit the block-local compaction
-    prefix and survivor count.  Blocks past the live count write inert
-    outputs and compute nothing — the same block-guard billing semantics
-    as the multi-kernel path's score kernels."""
-    *param_refs, scale_ref, ep_ref, en_ref, \
-        g_ref, act_ref, dec_ref, ex_ref, pfx_ref, cnt_ref = rest
-    params = tuple(param_refs)
-    bn = g0_ref.shape[0]
-    i = pl.program_id(0)
-    block_start = i * bn
-    nv = nv_ref[0]
-
-    @pl.when(block_start >= nv)
-    def _skip():
-        _write_outputs(
-            g_ref, act_ref, dec_ref, ex_ref, pfx_ref, cnt_ref,
-            g0_ref[...].astype(jnp.float32),
-            jnp.zeros((bn,), jnp.bool_),
-            jnp.zeros((bn,), jnp.bool_),
-            jnp.zeros((bn,), jnp.int32),
-            jnp.zeros((bn,), jnp.int32),
-            jnp.int32(0),
-        )
-
-    @pl.when(block_start < nv)
-    def _compute():
-        t0 = t0_ref[0]
-        if variant == "matrix":
-            (w_ref,) = params
-
-            def score_j(j):
-                col = pl.load(
-                    x_ref, (slice(None), pl.dslice(t0 + j, 1))
-                )[:, 0]
-                return jnp.where(j < w_ref[0, 0], col.astype(jnp.float32), 0.0)
-        elif variant == "tree":
-            feats_ref, thrs_ref, leaves_ref = params
-
-            def score_j(j):
-                return _tree_score_stage(
-                    x_ref, feats_ref[...], thrs_ref[...], leaves_ref[...],
-                    scale_ref[...], j, quant, lane_mode=False,
-                )
-        else:  # lattice
-            feats_ref, theta_ref = params
-
-            def score_j(j):
-                return _lattice_score_stage(
-                    x_ref, feats_ref[...], theta_ref[...],
-                    scale_ref[...], j, quant, lane_mode=False,
-                )
-
-        g, active, dec, ex, keep, pfx = _walk_and_pack(
-            score_j,
-            lambda j: ep_ref[0, j],
-            lambda j: en_ref[0, j],
-            g0_ref[...], nv, block_start, W,
-        )
-        _write_outputs(
-            g_ref, act_ref, dec_ref, ex_ref, pfx_ref, cnt_ref,
-            g, active, dec, ex, pfx, keep.sum(dtype=jnp.int32),
-        )
-
-
-def _combine_blocks(outs, keep, cap, bn):
-    """Turn per-block prefixes + counts into global pack positions: a
-    tiny (n_blocks,) exclusive scan instead of a cap-wide cumsum.
-    Retired/invalid lanes aim at ``cap`` (out of bounds, dropped)."""
-    g, act, dec, ex, pfx, cnt = outs
-    off = jnp.cumsum(cnt) - cnt  # exclusive per-block offsets
-    posg = pfx + jnp.repeat(off, bn, total_repeat_length=g.shape[0])
-    pack = jnp.where(keep, posg, cap)
-    return (
-        g[:cap], act[:cap], dec[:cap], ex[:cap], pack[:cap],
-        cnt.sum(dtype=jnp.int32),
-    )
 
 
 def mega_stage_pallas(
@@ -564,7 +569,7 @@ def mega_stage_pallas(
     eps_neg: jax.Array,
     *,
     block_n: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """One fused cascade stage step over a survivor buffer.
 
@@ -583,11 +588,8 @@ def mega_stage_pallas(
     cap = g0.shape[0]
     bn = min(block_n, cap) if cap else block_n
     pad = -cap % bn
-    if pad:
-        g0 = jnp.pad(g0, (0, pad))
-        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
     capp = cap + pad
-    nb = capp // bn
+    S, W = slabs.S, slabs.W
     i32 = jnp.int32
     scalars = (
         jnp.full((1,), stage, i32),
@@ -595,130 +597,39 @@ def mega_stage_pallas(
         jnp.full((1,), jnp.minimum(jnp.asarray(n_valid, i32), i32(cap))),
     )
 
-    def row(shape):  # per-row-block operands/outputs
-        return pl.BlockSpec(shape, lambda i, s, t0, nv: (i,) + (0,) * (len(shape) - 1))
+    def row(a):  # per-row-block operands
+        return pl.BlockSpec((bn, a.shape[1]), lambda i, s, t0, nv: (i, 0))
 
-    def slab(shape):  # per-stage operands, selected by the prefetched stage
-        return pl.BlockSpec(
-            shape, lambda i, s, t0, nv: (s[0],) + (0,) * (len(shape) - 1)
-        )
+    def slab(a):  # per-stage operands, selected by the prefetched stage
+        return pl.BlockSpec((None,) + a.shape[1:], lambda i, s, t0, nv: (s[0], 0, 0))
 
-    in_specs = [row((bn,)), row((bn,) + x.shape[1:])]
-    operands = [g0, x]
+    x = _pad_rows(x, pad)
+    operands = [_pad_rows(g0, pad).reshape(capp, 1), x]
     if slabs.variant == "matrix":
-        in_specs += [slab((1, 1))]
-        operands += [slabs.data["widths"]]
-    elif slabs.variant == "tree":
-        f, th, lv = slabs.data["feats"], slabs.data["thrs"], slabs.data["payload"]
-        in_specs += [slab((1,) + f.shape[1:]), slab((1,) + th.shape[1:]),
-                     slab((1,) + lv.shape[1:])]
-        operands += [f, th, lv]
-    else:  # lattice
-        f, th = slabs.data["feats"], slabs.data["payload"]
-        in_specs += [slab((1,) + f.shape[1:]), slab((1,) + th.shape[1:])]
-        operands += [f, th]
-    in_specs += [slab((1, 1)), slab((1, slabs.W)), slab((1, slabs.W))]
-    operands += [slabs.scale, eps_pos, eps_neg]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=[row((bn,))] * 5 + [pl.BlockSpec((1,), lambda i, s, t0, nv: (i,))],
+        params = [slabs.data["widths"].reshape(S, 1, 1)]
+    else:
+        f = slabs.data["feats"]
+        params = [feature_masks(f, x.shape[1]).reshape(S, -1, x.shape[1])]
+        if slabs.variant == "tree":
+            params.append(slabs.data["thrs"])
+        params.append(slabs.data["payload"])
+    params += [
+        slabs.scale.reshape(S, 1, 1),
+        jnp.asarray(eps_pos).reshape(S, 1, W),
+        jnp.asarray(eps_neg).reshape(S, 1, W),
+    ]
+    g, act, dec, ex = _launch(
+        slabs, scalars, operands + params,
+        [row(a) for a in operands] + [slab(a) for a in params],
+        cap, bn, lane_mode=False, interpret=interpret,
     )
-    kernel = functools.partial(
-        _mega_batch_kernel, variant=slabs.variant, quant=slabs.quant,
-        W=slabs.W,
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((capp,), jnp.float32),
-            jax.ShapeDtypeStruct((capp,), jnp.int32),
-            jax.ShapeDtypeStruct((capp,), jnp.int32),
-            jax.ShapeDtypeStruct((capp,), jnp.int32),
-            jax.ShapeDtypeStruct((capp,), jnp.int32),
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*scalars, *operands)
-    keep = outs[1].astype(bool)  # batch keep == still-active
-    return _combine_blocks(outs, keep, cap, bn)
+    keep = act.astype(bool)  # batch keep == still-active
+    return (g, act, dec, ex) + _pack(keep, cap)
 
 
 # ---------------------------------------------------------------------------
 # the lane megakernel (mixed-stage blocks, streaming admission)
 # ---------------------------------------------------------------------------
-
-
-def _mega_lane_kernel(
-    nv_ref,  # scalar prefetch
-    g0_ref, x_ref, *rest,
-    variant: str, quant: str, W: int,
-):
-    """The mixed-stage variant: every per-stage quantity (param slab,
-    scale, thresholds, last-stage flag) arrives pre-gathered PER LANE,
-    so one block can hold stage-0 rookies next to mid-cascade veterans
-    (the streaming refill).  Exits are relative; lanes flagged ``stop``
-    (their last stage) are excluded from the compaction prefix — they
-    retire this step whether they exit or run out."""
-    *param_refs, scale_ref, ep_ref, en_ref, stop_ref, \
-        g_ref, act_ref, dec_ref, ex_ref, pfx_ref, cnt_ref = rest
-    params = tuple(param_refs)
-    bn = g0_ref.shape[0]
-    i = pl.program_id(0)
-    block_start = i * bn
-    nv = nv_ref[0]
-
-    @pl.when(block_start >= nv)
-    def _skip():
-        _write_outputs(
-            g_ref, act_ref, dec_ref, ex_ref, pfx_ref, cnt_ref,
-            g0_ref[...].astype(jnp.float32),
-            jnp.zeros((bn,), jnp.bool_),
-            jnp.zeros((bn,), jnp.bool_),
-            jnp.zeros((bn,), jnp.int32),
-            jnp.zeros((bn,), jnp.int32),
-            jnp.int32(0),
-        )
-
-    @pl.when(block_start < nv)
-    def _compute():
-        if variant == "matrix":
-            (w_ref,) = params
-
-            def score_j(j):
-                return jnp.where(
-                    j < w_ref[:, 0], x_ref[:, j].astype(jnp.float32), 0.0
-                )
-        elif variant == "tree":
-            feats_ref, thrs_ref, leaves_ref = params
-
-            def score_j(j):
-                return _tree_score_stage(
-                    x_ref, feats_ref[...], thrs_ref[...], leaves_ref[...],
-                    scale_ref[...], j, quant, lane_mode=True,
-                )
-        else:  # lattice
-            feats_ref, theta_ref = params
-
-            def score_j(j):
-                return _lattice_score_stage(
-                    x_ref, feats_ref[...], theta_ref[...],
-                    scale_ref[...], j, quant, lane_mode=True,
-                )
-
-        g, active, dec, ex, keep, pfx = _walk_and_pack(
-            score_j,
-            lambda j: ep_ref[:, j],  # per-lane threshold columns
-            lambda j: en_ref[:, j],
-            g0_ref[...], nv, block_start, W,
-            stop=stop_ref[...] != 0,
-        )
-        _write_outputs(
-            g_ref, act_ref, dec_ref, ex_ref, pfx_ref, cnt_ref,
-            g, active, dec, ex, pfx, keep.sum(dtype=jnp.int32),
-        )
 
 
 def mega_lane_pallas(
@@ -732,7 +643,7 @@ def mega_lane_pallas(
     n_valid: jax.Array,
     *,
     block_n: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """One fused MIXED-stage step for the streaming executors.
 
@@ -742,80 +653,38 @@ def mega_lane_pallas(
     slabs plus the (cap, 1) scale (for matrix: the per-lane (cap, 1)
     true stage widths, used to mask overhang columns).  ``eps_pos_lane``/``eps_neg_lane``: the
     (cap, W) per-lane threshold rows.  ``stop``: (cap,) bool/int, 1 on a
-    lane running its LAST stage (excluded from the survivor prefix).
+    lane running its LAST stage (excluded from the survivor prefix: it
+    retires this step whether it exits or runs out).
 
     Same return contract as ``mega_stage_pallas``.
     """
     cap = g0.shape[0]
     bn = min(block_n, cap) if cap else block_n
     pad = -cap % bn
-    pad1 = lambda a: jnp.pad(  # noqa: E731
-        a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)
-    )
-    scale = lane_data.get("scale", jnp.take(slabs.scale, jnp.zeros(cap, jnp.int32), axis=0))
-    stop = jnp.asarray(stop).astype(jnp.int32)
-    if pad:
-        g0, x, stop = pad1(g0), pad1(x), pad1(stop)
-        scale = pad1(scale)
-        eps_pos_lane, eps_neg_lane = pad1(eps_pos_lane), pad1(eps_neg_lane)
-        lane_data = {
-            k: pad1(v) for k, v in lane_data.items() if k != "scale"
-        }
     capp = cap + pad
-    nb = capp // bn
     i32 = jnp.int32
     scalars = (
         jnp.full((1,), jnp.minimum(jnp.asarray(n_valid, i32), i32(cap))),
     )
-
-    def row(shape):
-        return pl.BlockSpec(
-            shape, lambda i, nv: (i,) + (0,) * (len(shape) - 1)
-        )
-
-    in_specs = [row((bn,)), row((bn,) + x.shape[1:])]
-    operands = [g0, x]
+    scale = lane_data.get(
+        "scale", jnp.take(slabs.scale, jnp.zeros(cap, i32), axis=0)
+    )
     if slabs.variant == "matrix":
-        in_specs += [row((bn, 1))]
-        operands += [lane_data["widths"]]
-    elif slabs.variant == "tree":
-        f, th, lv = (
-            lane_data["feats"], lane_data["thrs"], lane_data["payload"]
-        )
-        in_specs += [row((bn,) + f.shape[1:]), row((bn,) + th.shape[1:]),
-                     row((bn,) + lv.shape[1:])]
-        operands += [f, th, lv]
-    else:  # lattice
-        f, th = lane_data["feats"], lane_data["payload"]
-        in_specs += [row((bn,) + f.shape[1:]), row((bn,) + th.shape[1:])]
-        operands += [f, th]
-    in_specs += [
-        row((bn, 1)), row((bn, slabs.W)), row((bn, slabs.W)), row((bn,)),
+        params = [lane_data["widths"]]
+    else:
+        params = [lane_data["feats"]]
+        if slabs.variant == "tree":
+            params.append(lane_data["thrs"])
+        params.append(lane_data["payload"])
+    operands = [
+        _pad_rows(a.reshape(cap, -1), pad)
+        for a in [g0, x, *params, scale, eps_pos_lane, eps_neg_lane]
     ]
-    operands += [scale, eps_pos_lane, eps_neg_lane, stop]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=[row((bn,))] * 5 + [pl.BlockSpec((1,), lambda i, nv: (i,))],
+    g, act, dec, ex = _launch(
+        slabs, scalars, operands,
+        [pl.BlockSpec((bn, a.shape[1]), lambda i, nv: (i, 0)) for a in operands],
+        cap, bn, lane_mode=True, interpret=interpret,
     )
-    kernel = functools.partial(
-        _mega_lane_kernel, variant=slabs.variant, quant=slabs.quant,
-        W=slabs.W,
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((capp,), jnp.float32),
-            jax.ShapeDtypeStruct((capp,), jnp.int32),
-            jax.ShapeDtypeStruct((capp,), jnp.int32),
-            jax.ShapeDtypeStruct((capp,), jnp.int32),
-            jax.ShapeDtypeStruct((capp,), jnp.int32),
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*scalars, *operands)
-    keep = outs[1].astype(bool) & (stop == 0)  # survivors advance a stage
-    return _combine_blocks(outs, keep, cap, bn)
-
+    # survivors advance a stage; lanes on their last stage retire
+    keep = act.astype(bool) & (jnp.asarray(stop).astype(i32) == 0)
+    return (g, act, dec, ex) + _pack(keep, cap)

@@ -1,14 +1,15 @@
 """Public jit'd entry points for the Pallas kernels.
 
-Each op dispatches to the Pallas kernel (interpret=True on CPU — the kernel
-body executes in Python for bit-level validation; on TPU set
-``repro.kernels.INTERPRET = False`` / pass interpret=False) and is paired
-with a pure-jnp oracle in ``ref.py``.
+Each op dispatches to the Pallas kernel and is paired with a pure-jnp
+oracle in ``ref.py``.  Kernels compile with Mosaic on a TPU and run in
+Pallas interpret mode where their arrays live on the CPU (the kernel body
+executes as ordinary XLA ops, bit-level semantics intact); the choice is
+made per call (``repro.kernels.interpret``), and an explicit
+``interpret=`` overrides it.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -29,19 +30,13 @@ __all__ = [
     "ref",
 ]
 
-# Flip to False when running on real TPU hardware.
-INTERPRET = jax.default_backend() != "tpu"
-
-
 def cascade_decide(scores_ordered, eps_pos, eps_neg, beta, **kw):
     """Early-exit cascade -> (decisions int32, exit_step int32)."""
-    kw.setdefault("interpret", INTERPRET)
     return cascade_pallas(scores_ordered, eps_pos, eps_neg, beta, **kw)
 
 
 def cascade_chunk(g0, chunk_scores, eps_pos, eps_neg, t0, **kw):
     """One-stage threshold tests -> (g, active, decided_pos, exit_step)."""
-    kw.setdefault("interpret", INTERPRET)
     return cascade_chunk_pallas(g0, chunk_scores, eps_pos, eps_neg, t0, **kw)
 
 
@@ -57,7 +52,6 @@ def kernel_decide_fn(block_n: int = 256, interpret: bool | None = None):
     exit steps are unaffected (same contract the eager ``cascade_decide``
     path has always relied on).
     """
-    it = INTERPRET if interpret is None else interpret
 
     def decide(g0, chunk, eps_pos, eps_neg, t0):
         dt = jnp.asarray(chunk).dtype
@@ -70,7 +64,7 @@ def kernel_decide_fn(block_n: int = 256, interpret: bool | None = None):
             jnp.asarray(eps_neg, dtype=dt),
             int(t0),
             block_n=block_n,
-            interpret=it,
+            interpret=interpret,
         )
         return (
             np.asarray(g),
@@ -211,7 +205,6 @@ def _bucket_rows(kw):
 
 def lattice_scores(theta, feats, x, **kw):
     """(N, T) lattice base-model scores (or a t0/t1/rows-restricted slab)."""
-    kw.setdefault("interpret", INTERPRET)
     m = _bucket_rows(kw)
     out = lattice_scores_pallas(theta, feats, x, **kw)
     return out if m is None else out[:m]
@@ -219,7 +212,6 @@ def lattice_scores(theta, feats, x, **kw):
 
 def gbt_scores(feats, thrs, leaves, x, **kw):
     """(N, T) oblivious-tree base-model scores (or a t0/t1/rows slab)."""
-    kw.setdefault("interpret", INTERPRET)
     m = _bucket_rows(kw)
     out = gbt_scores_pallas(feats, thrs, leaves, x, **kw)
     return out if m is None else out[:m]

@@ -65,7 +65,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.executor import CascadePlan, ChunkStat, ExecutorResult
@@ -77,19 +76,20 @@ from repro.kernels.cascade_kernel import (
 )
 from repro.kernels.device_executor import (
     DEFAULT_BLOCK_N,
-    INTERPRET,
     BoundScorer,
     DevicePlan,
     GroupedResult,
     StreamResult,
     WaveFailure,  # noqa: F401 — re-export: sharded waves raise the same type
     check_batch_finite,
+    compile_program,
     group_topk_rows,
     launch_wave,
     repack_state,
     stream_occupancy,
 )
 
+from repro.kernels.interpret import platform_interpret
 from repro.launch.shardings import model_stacked_shardings, split_columns
 
 __all__ = ["ShardedDeviceExecutor", "critical_blocks"]
@@ -211,7 +211,13 @@ class ShardedDeviceExecutor:
         self.check_finite = bool(check_finite)
         self.mesh = mesh
         self.block_n = max(1, int(block_n))
-        self.interpret = INTERPRET if interpret is None else interpret
+        # kernels run interpreted exactly where the mesh's devices are CPUs
+        self.interpret = (
+            platform_interpret(mesh.devices.flat[0].platform)
+            if interpret is None
+            else bool(interpret)
+        )
+        self._compiled: set = set()  # argument signatures compiled so far
         self.rebalance = bool(rebalance)
         self.rebalance_ratio = float(rebalance_ratio)
         self.traces = 0
@@ -268,7 +274,7 @@ class ShardedDeviceExecutor:
         ``xbuf``/``idbuf``/``n_live`` arrive with a leading length-1 shard
         axis (shard_map splits the mesh axis); outputs keep it so every
         out_spec is sharded over ``"data"`` (no replicated out_specs —
-        ``check_rep=False`` friendly).
+        ``check_vma=False`` friendly).
 
         On a 2-D mesh (``model_shards > 1``) the SAME body runs with two
         changes, both resolved at trace time so the 1-D trace is
@@ -500,12 +506,12 @@ class ShardedDeviceExecutor:
         xbuf = jnp.take(x, idbuf.reshape(-1), axis=0).reshape(
             (shards, cap_l) + x.shape[1:]
         )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             self._per_shard,
             mesh=self.mesh,
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
             out_specs=(P(DATA_AXIS),) * 7,
-            check_rep=False,
+            check_vma=False,
         )
         return sharded(xbuf, idbuf, n_live0)
 
@@ -517,7 +523,7 @@ class ShardedDeviceExecutor:
         slices split one per model shard (``in_specs=P("model")`` on the
         leading axis).  Outputs carry two leading length-1 axes so every
         out_spec is ``P("data", "model")`` — no replicated out_specs,
-        same ``check_rep=False`` convention as the 1-D program."""
+        same ``check_vma=False`` convention as the 1-D program."""
         self.traces += 1  # trace-time side effect, read by the trace tests
         shards = self.shards
         cap_l = idbuf.shape[1]
@@ -527,12 +533,12 @@ class ShardedDeviceExecutor:
             (shards, cap_l) + x.shape[1:]
         )
         mp_specs = jax.tree_util.tree_map(lambda _: P(MODEL_AXIS), mparams)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             self._per_shard,
             mesh=self.mesh,
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS), mp_specs),
             out_specs=(P(DATA_AXIS, MODEL_AXIS),) * 7,
-            check_rep=False,
+            check_vma=False,
         )
         return sharded(xbuf, idbuf, n_live0, mparams)
 
@@ -606,13 +612,14 @@ class ShardedDeviceExecutor:
             idbuf[k, :cnt] = order[start : start + cnt]
             n_live0[k] = cnt
             start += cnt
+        args = (x, jnp.asarray(idbuf), jnp.asarray(n_live0))
         if self.model_shards > 1:
-            dec, ex, gout, s_f, n_f, n_in_log, reb_log = launch_wave(
-                "sharded",
-                lambda: self._jit(
-                    x, jnp.asarray(idbuf), jnp.asarray(n_live0), self._mparams
-                ),
-            )
+            args += (self._mparams,)
+        compile_program(self._compiled, self._jit, *args)
+        dec, ex, gout, s_f, n_f, n_in_log, reb_log = launch_wave(
+            "sharded", lambda: self._jit(*args)
+        )
+        if self.model_shards > 1:
             # 2-D outputs carry (data, model) leading axes; everything is
             # identical across model replicas, so read model coordinate 0
             dec = np.asarray(dec)[0, 0][:n].astype(bool)
@@ -623,10 +630,6 @@ class ShardedDeviceExecutor:
             n_in_log = np.asarray(n_in_log)[:, 0, :]
             reb_log = np.asarray(reb_log)[:, 0, :]
         else:
-            dec, ex, gout, s_f, n_f, n_in_log, reb_log = launch_wave(
-                "sharded",
-                lambda: self._jit(x, jnp.asarray(idbuf), jnp.asarray(n_live0)),
-            )
             dec = np.asarray(dec)[0][:n].astype(bool)
             ex = np.asarray(ex, dtype=np.int64)[0][:n]
             gout = np.asarray(gout)[0][:n]
@@ -856,16 +859,16 @@ class ShardedDeviceExecutor:
             (shards, L) + x.shape[1:]
         )
         # the threshold vector rides in sharded (every shard gets the
-        # same copy) — no replicated in_specs, check_rep=False friendly
+        # same copy) — no replicated in_specs, check_vma=False friendly
         eps_rep = jnp.broadcast_to(eps_g[None, :], (shards, eps_g.shape[0]))
-        sharded = shard_map(
+        sharded = jax.shard_map(
             lambda xb, gi, ro, va, n, ep: self._grouped_per_shard(
                 k, xb, gi, ro, va, n, ep
             ),
             mesh=self.mesh,
             in_specs=(P(DATA_AXIS),) * 6,
             out_specs=(P(DATA_AXIS),) * 6,
-            check_rep=False,
+            check_vma=False,
         )
         return sharded(xbuf, gids, rows, valid, n0, eps_rep)
 
@@ -941,17 +944,18 @@ class ShardedDeviceExecutor:
             valid_init[j, :cnt] = group_valid[start : start + cnt].astype(np.int32)
             n0[j] = cnt
             start += cnt
+        args = (
+            int(k),
+            x,
+            jnp.asarray(gids),
+            jnp.asarray(rows_init),
+            jnp.asarray(valid_init),
+            jnp.asarray(n0),
+            jnp.asarray(eps_g, dtype=jnp.float32),
+        )
+        compile_program(self._compiled, self._grouped_jit, *args, static=1)
         verd, exst, marg, s_f, n_f, n_in_log = launch_wave(
-            "sharded",
-            lambda: self._grouped_jit(
-                int(k),
-                x,
-                jnp.asarray(gids),
-                jnp.asarray(rows_init),
-                jnp.asarray(valid_init),
-                jnp.asarray(n0),
-                jnp.asarray(eps_g, dtype=jnp.float32),
-            ),
+            "sharded", lambda: self._grouped_jit(*args)
         )
         verd = np.asarray(verd)[0][:n_groups]
         exst = np.asarray(exst, dtype=np.int64)[0][:n_groups]
@@ -1184,14 +1188,14 @@ class ShardedDeviceExecutor:
         ring_x = jnp.take(x, ring_ids.reshape(-1), axis=0).reshape(
             (shards, R_l) + x.shape[1:]
         )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             lambda rx, ri, ar, ct: self._stream_per_shard(
                 cap_l, rx, ri, ar, ct
             ),
             mesh=self.mesh,
             in_specs=(P(DATA_AXIS),) * 4,
             out_specs=(P(DATA_AXIS),) * 6,
-            check_rep=False,
+            check_vma=False,
         )
         return sharded(ring_x, ring_ids, arrivals, counts)
 
@@ -1277,15 +1281,16 @@ class ShardedDeviceExecutor:
             ring_ids[k, : ids_k.size] = ids_k
             ring_arr[k, : ids_k.size] = arr[ids_k]
             counts[k] = ids_k.size
+        args = (
+            cap_l,
+            x,
+            jnp.asarray(ring_ids),
+            jnp.asarray(ring_arr),
+            jnp.asarray(counts),
+        )
+        compile_program(self._compiled, self._stream_jit, *args, static=1)
         dec, ex, gout, admit, done, s_f = launch_wave(
-            "sharded",
-            lambda: self._stream_jit(
-                cap_l,
-                x,
-                jnp.asarray(ring_ids),
-                jnp.asarray(ring_arr),
-                jnp.asarray(counts),
-            ),
+            "sharded", lambda: self._stream_jit(*args)
         )
         steps_run = int(np.asarray(s_f)[0])
         dec = np.asarray(dec)[0][:n].astype(bool)

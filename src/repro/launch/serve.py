@@ -33,6 +33,7 @@ from repro.data.synthetic import make_dataset
 from repro.ensembles.gbt import train_gbt
 from repro.ensembles.lattice import init_lattice_ensemble, train_lattice_ensemble
 from repro.kernels import ops
+from repro.launch.compile_cache import setup_compile_cache
 from repro.serving.engine import BACKENDS as POLICIES
 from repro.serving.engine import QWYCServer, StreamingServer
 
@@ -286,6 +287,7 @@ def _serve_ranking(args, ds, score_fn, F_train, beta, backend_name, backend_opts
 def main() -> None:
     ap = build_parser()
     args = ap.parse_args()
+    setup_compile_cache()
     backend_name, backend_opts, policy = resolve_backend_args(args)
     backend = resolve_backend(backend_name)
     if backend_opts.get("rebalance") and not backend.capabilities.supports_rebalance:

@@ -75,7 +75,11 @@ from repro.api.backends import BackoffPolicy, DegradationLadder
 from repro.core.executor import CascadePlan, matrix_producer
 from repro.core.qwyc import QWYCModel
 from repro.kernels import ops
-from repro.kernels.device_executor import DevicePlan, matrix_stage_scorer
+from repro.kernels.device_executor import (
+    DevicePlan,
+    compile_program,
+    matrix_stage_scorer,
+)
 from repro.serving.watchdog import DriftWatchdog, WatchdogConfig, widen_plan
 
 __all__ = ["ServeStats", "QWYCServer", "StreamingServer"]
@@ -518,10 +522,15 @@ class QWYCServer:
             cap = executor._cap(self.flush_size)
             rows_all = jnp.arange(cap, dtype=jnp.int32)
 
-            def key_fn(x, n, _s=scorer, _r=rows_all):
+            def key_scores(x, n, _s=scorer, _r=rows_all):
                 return _s.fn(x, _r, jnp.int32(0), n)[:, 0]
 
-            key_fn = jax.jit(key_fn)
+            jitted, compiled = jax.jit(key_scores), set()
+
+            def key_fn(x, n):
+                # a refused program raises here, past the wave ladder
+                compile_program(compiled, jitted, x, n)
+                return jitted(x, n)
         self._dev = (executor, scorer, eager_matrix, key_fn)
         self._dev_cache[key] = self._dev
         return self._dev

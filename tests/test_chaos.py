@@ -9,6 +9,8 @@ multi-shard cases need forged XLA devices, as the CI chaos job provides:
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -27,6 +29,7 @@ from repro.kernels.cascade_kernel import (  # noqa: E402
     cascade_lane_pallas,
 )
 from repro.kernels.device_executor import (  # noqa: E402
+    DeviceProgramError,
     DevicePlan,
     WaveFailure,
     matrix_stage_scorer,
@@ -401,6 +404,41 @@ def test_server_wave_fault_falls_to_host_floor():
     for g, w in zip(got, want):
         assert g["decision"] == w["decision"]
         assert g["models_evaluated"] == w["models_evaluated"]
+
+
+@pytest.mark.parametrize("policy", ["kernel", "sorted-kernel"])
+@pytest.mark.parametrize("error", [RuntimeError, NotImplementedError, Exception])
+def test_refused_device_program_raises_without_fallback(policy, error):
+    """A device program the compiler refuses is a bug, not a runtime
+    fault: serve() and evaluate() raise before any wave launches, record
+    no DegradationEvent, and no host rung answers in the device's place
+    (the runtime wave faults above still take the ladder)."""
+    rng, Xc, score_fn, m = _linear_world(seed=23)
+    Xt = rng.normal(size=(32, Xc.shape[1])).astype(np.float32)
+
+    def refused(dplan):
+        def fn(x, rows, t0, n_valid):
+            raise error("compiler refused the stage program")
+
+        return dataclasses.replace(
+            matrix_stage_scorer(dplan), fn=fn, lane_fn=None, slabs=None,
+            prepare=lambda x: jnp.asarray(x, dtype=jnp.float32),
+        )
+
+    compiled = api.fit(score_fn, Xc, alpha=0.02, chunk_t=4).compile(
+        "device", scorer=api.FunctionScorer(refused), **NO_SLEEP
+    )
+    srv = compiled.serve(batch_size=16, policy=policy)
+    with pytest.raises(DeviceProgramError, match="refused"):
+        for x in Xt:
+            srv.submit(x)
+        srv.drain()
+    assert srv.stats.degradation_events == []
+    assert srv.exec.name == "device" and srv.stats.n_requests == 0
+    with pytest.raises(DeviceProgramError, match="refused"):
+        compiled.evaluate(x=Xt)
+    assert compiled.degradation_events == []
+    assert compiled.backend_name == "device"
 
 
 # ------------------------------------------------- server: quarantine
