@@ -68,7 +68,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import tracing
 from repro.core.executor import CascadePlan, ChunkStat, ExecutorResult
 from repro.kernels import megakernel as mk
 from repro.kernels.cascade_kernel import (
@@ -118,7 +120,8 @@ def compile_program(compiled: set, jitted, *args, static: int = 0) -> None:
     if key in compiled:
         return
     try:
-        jitted.lower(*args).compile()
+        with TraceAnnotation(tracing.COMPILE):
+            jitted.lower(*args).compile()
     except (ValueError, TypeError):
         raise
     except Exception as e:
@@ -829,6 +832,9 @@ class DeviceExecutor:
         col_valid = jnp.asarray(dp.col_valid)
         lane = jnp.arange(cap, dtype=jnp.int32)
 
+        # a stage's ops are compaction, but for its scoring and decide
+        # kernels (the inner scope)
+        @jax.named_scope(tracing.COMPACT)
         def body(carry):
             # stage semantics mirrored by ShardedDeviceExecutor._per_shard
             # (scatter targets differ: buffer rows here, global ids there)
@@ -844,14 +850,15 @@ class DeviceExecutor:
                 # trip, and the pack positions come back ready to
                 # scatter (DESIGN.md §9)
                 xr = jnp.take(x, rows, axis=0)  # trash indices clamp
-                g_new, active, dpos, ex_rel, pack, n_keep = (
-                    mk.mega_stage_pallas(
-                        self.scorer.slabs, xr, g_rows, s, t0, n_active,
-                        eps_pos, eps_neg,
-                        block_n=self._bn_bill(),
-                        interpret=self.interpret,
+                with jax.named_scope(tracing.SCORE_DECIDE):
+                    g_new, active, dpos, ex_rel, pack, n_keep = (
+                        mk.mega_stage_pallas(
+                            self.scorer.slabs, xr, g_rows, s, t0, n_active,
+                            eps_pos, eps_neg,
+                            block_n=self._bn_bill(),
+                            interpret=self.interpret,
+                        )
                     )
-                )
                 state_new = state  # megakernel path is stateless-only
             else:
                 # multi-kernel fallback (the parity oracle): score the
@@ -860,20 +867,21 @@ class DeviceExecutor:
                 # packed); padded columns are zeroed so they cannot move
                 # a partial sum.  Stateful scorers return the carried
                 # per-lane state alongside the scores.
-                scores, state_new = self.scorer.stage(
-                    state, t0, t0 + W, rows, x, n_active
-                )
-                scores = jnp.where(col_valid[s][None, :], scores, 0.0)
-                g_new, active, dpos, ex_rel = cascade_chunk_pallas(
-                    g_rows,
-                    scores,
-                    eps_pos[s],
-                    eps_neg[s],
-                    0,
-                    block_n=self.block_n,
-                    interpret=self.interpret,
-                    n_valid=n_active,
-                )
+                with jax.named_scope(tracing.SCORE_DECIDE):
+                    scores, state_new = self.scorer.stage(
+                        state, t0, t0 + W, rows, x, n_active
+                    )
+                    scores = jnp.where(col_valid[s][None, :], scores, 0.0)
+                    g_new, active, dpos, ex_rel = cascade_chunk_pallas(
+                        g_rows,
+                        scores,
+                        eps_pos[s],
+                        eps_neg[s],
+                        0,
+                        block_n=self.block_n,
+                        interpret=self.interpret,
+                        n_valid=n_active,
+                    )
                 # cumsum-prefix compaction: rank survivors (stable) and
                 # pack them at the front of the fixed-capacity buffer
                 keep = active.astype(bool) & (lane < n_active)
@@ -918,11 +926,12 @@ class DeviceExecutor:
             cond, body, init
         )
         # rows that never exited: classified by the full ensemble score
-        lane_valid = lane < n_f
-        dec = dec.at[jnp.where(lane_valid, rows_f, cap)].set(
-            jnp.take(g, rows_f, axis=0) >= jnp.float32(self.dplan.plan.beta),
-            mode="drop",
-        )
+        with jax.named_scope(tracing.FINALIZE):
+            lane_valid = lane < n_f
+            dec = dec.at[jnp.where(lane_valid, rows_f, cap)].set(
+                jnp.take(g, rows_f, axis=0) >= jnp.float32(self.dplan.plan.beta),
+                mode="drop",
+            )
         return dec, ex, g, s_f, n_f, n_in_log
 
     def run(
@@ -958,49 +967,52 @@ class DeviceExecutor:
                 scores_computed=0,
                 scores_possible=0,
             )
-        if self.check_finite:
-            check_batch_finite(batch, n)
-        cap = self._cap(max(n, capacity or 0))
-        x = self._cast_operand(batch if prepared else self.scorer.prepare(batch))
-        if x.shape[0] < cap:
-            x = jnp.pad(x, ((0, cap - x.shape[0]),) + ((0, 0),) * (x.ndim - 1))
-        rows = (
-            np.arange(n, dtype=np.int32)
-            if row_order is None
-            else np.asarray(row_order, dtype=np.int32)
-        )
-        assert rows.shape == (n,)
-        rows_init = np.full(cap, cap, dtype=np.int32)
-        rows_init[:n] = rows
-        args = (x, jnp.asarray(rows_init), n)
-        compile_program(self._compiled, self._jit, *args)
-        dec, ex, g, s_f, n_f, n_in_log = launch_wave(
-            "device", lambda: self._jit(*args)
-        )
-        dec = np.asarray(dec)[:n]
-        ex = np.asarray(ex, dtype=np.int64)[:n]
-        g = np.asarray(g)[:n]
-        s_f, n_f = int(s_f), int(n_f)
-        n_in_log = np.asarray(n_in_log)
-        stages = plan.stages
-        # bill at the SCORER's kernel block size (the granularity its
-        # block guard really computes at), not the executor's buffer block
-        bn, W = self.scorer.block_n or self.block_n, self.dplan.W
-        chunk_stats = []
-        for s in range(s_f):
-            n_in = int(n_in_log[s])
-            n_next = int(n_in_log[s + 1]) if s + 1 < s_f else n_f
-            # block-guard billing: the score kernel computed the live
-            # blocks of the W-wide slab, not the whole capacity
-            chunk_stats.append(
-                ChunkStat(
-                    t0=stages[s][0],
-                    t1=stages[s][1],
-                    n_in=n_in,
-                    n_exited=n_in - n_next,
-                    scores_computed=-(-n_in // bn) * bn * W,
-                )
+        with TraceAnnotation(tracing.RUN_DISPATCH):
+            if self.check_finite:
+                check_batch_finite(batch, n)
+            cap = self._cap(max(n, capacity or 0))
+            x = self._cast_operand(batch if prepared else self.scorer.prepare(batch))
+            if x.shape[0] < cap:
+                x = jnp.pad(x, ((0, cap - x.shape[0]),) + ((0, 0),) * (x.ndim - 1))
+            rows = (
+                np.arange(n, dtype=np.int32)
+                if row_order is None
+                else np.asarray(row_order, dtype=np.int32)
             )
+            assert rows.shape == (n,)
+            rows_init = np.full(cap, cap, dtype=np.int32)
+            rows_init[:n] = rows
+            args = (x, jnp.asarray(rows_init), n)
+            compile_program(self._compiled, self._jit, *args)
+            dec, ex, g, s_f, n_f, n_in_log = launch_wave(
+                "device", lambda: self._jit(*args)
+            )
+        with TraceAnnotation(tracing.RUN_FETCH):
+            dec = np.asarray(dec)[:n]
+            ex = np.asarray(ex, dtype=np.int64)[:n]
+            g = np.asarray(g)[:n]
+            s_f, n_f = int(s_f), int(n_f)
+            n_in_log = np.asarray(n_in_log)
+        with TraceAnnotation(tracing.RUN_STATS):
+            stages = plan.stages
+            # bill at the SCORER's kernel block size (the granularity its
+            # block guard really computes at), not the executor's buffer block
+            bn, W = self.scorer.block_n or self.block_n, self.dplan.W
+            chunk_stats = []
+            for s in range(s_f):
+                n_in = int(n_in_log[s])
+                n_next = int(n_in_log[s + 1]) if s + 1 < s_f else n_f
+                # block-guard billing: the score kernel computed the live
+                # blocks of the W-wide slab, not the whole capacity
+                chunk_stats.append(
+                    ChunkStat(
+                        t0=stages[s][0],
+                        t1=stages[s][1],
+                        n_in=n_in,
+                        n_exited=n_in - n_next,
+                        scores_computed=-(-n_in // bn) * bn * W,
+                    )
+                )
         return ExecutorResult(
             decisions=dec.astype(bool),
             exit_step=ex,

@@ -65,8 +65,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
 from repro.core.executor import CascadePlan, ChunkStat, ExecutorResult
 from repro.kernels import megakernel as mk
 from repro.kernels.cascade_kernel import (
@@ -323,9 +325,10 @@ class ShardedDeviceExecutor:
             def migrate(buf):
                 # gather -> global stable repack -> even re-split, one
                 # per-lane leaf at a time (operand and state alike)
-                flat = jax.lax.all_gather(buf, DATA_AXIS).reshape(
-                    (cap_g,) + buf.shape[1:]
-                )
+                with jax.named_scope(tracing.COLLECTIVE):
+                    flat = jax.lax.all_gather(buf, DATA_AXIS).reshape(
+                        (cap_g,) + buf.shape[1:]
+                    )
                 packed = (
                     jnp.zeros_like(flat).at[scat].set(flat, mode="drop")
                 )
@@ -338,7 +341,8 @@ class ShardedDeviceExecutor:
             xbuf = migrate(xbuf)
             state = jax.tree_util.tree_map(migrate, state)
             gbuf = migrate(gbuf)
-            flat_id = jax.lax.all_gather(idbuf, DATA_AXIS).reshape(cap_g)
+            with jax.named_scope(tracing.COLLECTIVE):
+                flat_id = jax.lax.all_gather(idbuf, DATA_AXIS).reshape(cap_g)
             packed_id = (
                 jnp.full((cap_g,), cap_g, dtype=jnp.int32)
                 .at[scat]
@@ -347,6 +351,9 @@ class ShardedDeviceExecutor:
             idbuf = jax.lax.dynamic_slice(packed_id, (start,), (cap_l,))
             return xbuf, state, gbuf, idbuf, cnt
 
+        # a stage's ops are compaction, but for its scoring and decide
+        # kernels and its collectives (the inner scopes)
+        @jax.named_scope(tracing.COMPACT)
         def body(carry):
             # fused stage semantics mirror DeviceExecutor._program's body
             # (score -> mask -> decide -> exit scatter -> cumsum-prefix
@@ -362,50 +369,53 @@ class ShardedDeviceExecutor:
                 # ONE fused kernel over the shard-local survivor buffer
                 # (which IS the gathered operand here — identity gather),
                 # same contract as DeviceExecutor's batch branch
-                g_new, active, dpos, ex_rel, pack, n_keep = (
-                    mk.mega_stage_pallas(
-                        self.scorer.slabs, xbuf, gbuf, s, t0, n_live,
-                        eps_pos, eps_neg,
-                        block_n=bn_bill,
-                        interpret=self.interpret,
+                with jax.named_scope(tracing.SCORE_DECIDE):
+                    g_new, active, dpos, ex_rel, pack, n_keep = (
+                        mk.mega_stage_pallas(
+                            self.scorer.slabs, xbuf, gbuf, s, t0, n_live,
+                            eps_pos, eps_neg,
+                            block_n=bn_bill,
+                            interpret=self.interpret,
+                        )
                     )
-                )
                 state_new = state  # megakernel path is stateless-only
             else:
-                if two_d:
-                    # each model shard scores ONLY its contiguous column
-                    # slice [c0, c0 + w_local) of stage s, scatters it
-                    # into a zeroed (cap_l, w_global) block, and ONE psum
-                    # over "model" — the single collective this stage
-                    # step gains — reassembles the full block bit-exactly
-                    # (disjoint column support; adding exact zeros
-                    # preserves f32 bits)
-                    scores_l = self._col_fn(mp, xbuf, lane, s, t0, c0, n_live)
-                    block = jax.lax.dynamic_update_slice(
-                        jnp.zeros((cap_l, self._w_global), dtype=jnp.float32),
-                        scores_l.astype(jnp.float32),
-                        (jnp.int32(0), c0),
+                with jax.named_scope(tracing.SCORE_DECIDE):
+                    if two_d:
+                        # each model shard scores ONLY its contiguous column
+                        # slice [c0, c0 + w_local) of stage s, scatters it
+                        # into a zeroed (cap_l, w_global) block, and ONE psum
+                        # over "model" — the single collective this stage
+                        # step gains — reassembles the full block bit-exactly
+                        # (disjoint column support; adding exact zeros
+                        # preserves f32 bits)
+                        scores_l = self._col_fn(mp, xbuf, lane, s, t0, c0, n_live)
+                        block = jax.lax.dynamic_update_slice(
+                            jnp.zeros((cap_l, self._w_global), dtype=jnp.float32),
+                            scores_l.astype(jnp.float32),
+                            (jnp.int32(0), c0),
+                        )
+                        with jax.named_scope(tracing.COLLECTIVE):
+                            scores = jax.lax.psum(block, MODEL_AXIS)[:, :W]
+                        state_new = state  # 2-D path is stateless-only
+                    else:
+                        # the survivor buffer IS the row set, so the scorer's
+                        # gather is the identity over cap_l local rows (never
+                        # the global batch)
+                        scores, state_new = self.scorer.stage(
+                            state, t0, t0 + W, lane, xbuf, n_live
+                        )
+                    scores = jnp.where(col_valid[s][None, :], scores, 0.0)
+                    g_new, active, dpos, ex_rel = cascade_chunk_pallas(
+                        gbuf,
+                        scores,
+                        eps_pos[s],
+                        eps_neg[s],
+                        0,
+                        block_n=self.block_n,
+                        interpret=self.interpret,
+                        n_valid=n_live,
                     )
-                    scores = jax.lax.psum(block, MODEL_AXIS)[:, :W]
-                    state_new = state  # 2-D path is stateless-only
-                else:
-                    # the survivor buffer IS the row set, so the scorer's
-                    # gather is the identity over cap_l local rows (never
-                    # the global batch)
-                    scores, state_new = self.scorer.stage(
-                        state, t0, t0 + W, lane, xbuf, n_live
-                    )
-                scores = jnp.where(col_valid[s][None, :], scores, 0.0)
-                g_new, active, dpos, ex_rel = cascade_chunk_pallas(
-                    gbuf,
-                    scores,
-                    eps_pos[s],
-                    eps_neg[s],
-                    0,
-                    block_n=self.block_n,
-                    interpret=self.interpret,
-                    n_valid=n_live,
-                )
                 # cumsum-prefix compaction, local to the shard
                 keep = active.astype(bool) & (lane < n_live)
                 pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
@@ -430,7 +440,8 @@ class ShardedDeviceExecutor:
             n_live = n_keep
             # occupancy census: one small all_gather per stage drives both
             # the replicated exit total and the rebalance trigger
-            counts = jax.lax.all_gather(n_live, DATA_AXIS)
+            with jax.named_scope(tracing.COLLECTIVE):
+                counts = jax.lax.all_gather(n_live, DATA_AXIS)
             total = counts.sum(dtype=jnp.int32)
             if self.rebalance:
                 balanced = -(-total // shards)
@@ -460,7 +471,8 @@ class ShardedDeviceExecutor:
             # quit when you can, mesh-wide: the psum'd live total hits zero
             return (s < S) & (total > 0)
 
-        total0 = jax.lax.psum(n_live, DATA_AXIS)
+        with jax.named_scope(tracing.COLLECTIVE):
+            total0 = jax.lax.psum(n_live, DATA_AXIS)
         init = (
             jnp.int32(0),
             xbuf,
@@ -479,16 +491,18 @@ class ShardedDeviceExecutor:
          n_in_log, reb_log, _) = jax.lax.while_loop(cond, body, init)
         # rows that never exited: classified by the full ensemble score,
         # written through the same exactly-once id scatter
-        lane_valid = lane < n_live
-        scat = jnp.where(lane_valid, idbuf, cap_g)
-        dec = dec.at[scat].set(
-            (gbuf >= jnp.float32(dp.plan.beta)).astype(jnp.int32), mode="drop"
-        )
-        ex = ex.at[scat].set(jnp.full((cap_l,), T, jnp.int32), mode="drop")
-        gout = gout.at[scat].set(gbuf, mode="drop")
-        dec = jax.lax.psum(dec, DATA_AXIS)
-        ex = jax.lax.psum(ex, DATA_AXIS)
-        gout = jax.lax.psum(gout, DATA_AXIS)
+        with jax.named_scope(tracing.FINALIZE):
+            lane_valid = lane < n_live
+            scat = jnp.where(lane_valid, idbuf, cap_g)
+            dec = dec.at[scat].set(
+                (gbuf >= jnp.float32(dp.plan.beta)).astype(jnp.int32), mode="drop"
+            )
+            ex = ex.at[scat].set(jnp.full((cap_l,), T, jnp.int32), mode="drop")
+            gout = gout.at[scat].set(gbuf, mode="drop")
+            with jax.named_scope(tracing.COLLECTIVE):
+                dec = jax.lax.psum(dec, DATA_AXIS)
+                ex = jax.lax.psum(ex, DATA_AXIS)
+                gout = jax.lax.psum(gout, DATA_AXIS)
         lead = (1, 1) if two_d else (1,)
         one = lambda a: jnp.reshape(a, lead + a.shape)  # noqa: E731
         return (
@@ -586,104 +600,107 @@ class ShardedDeviceExecutor:
                 f"block-padded to {self.block_n} — pass capacity >= n "
                 "(or None to size from the batch)"
             )
-        cap_l = self._cap_local(max(n, capacity or 0))
-        cap_g = shards * cap_l
-        x = self._cast_operand(batch if prepared else self.scorer.prepare(batch))
-        if x.shape[0] < cap_g:
-            x = jnp.pad(x, ((0, cap_g - x.shape[0]),) + ((0, 0),) * (x.ndim - 1))
-        order = (
-            np.arange(n, dtype=np.int32)
-            if row_order is None
-            else np.asarray(row_order, dtype=np.int32)
-        )
-        if order.shape != (n,):
-            raise ValueError(
-                f"row_order must be a ({n},)-shaped ordering of the "
-                f"batch rows, got shape {tuple(order.shape)}"
+        with TraceAnnotation(tracing.RUN_DISPATCH):
+            cap_l = self._cap_local(max(n, capacity or 0))
+            cap_g = shards * cap_l
+            x = self._cast_operand(batch if prepared else self.scorer.prepare(batch))
+            if x.shape[0] < cap_g:
+                x = jnp.pad(x, ((0, cap_g - x.shape[0]),) + ((0, 0),) * (x.ndim - 1))
+            order = (
+                np.arange(n, dtype=np.int32)
+                if row_order is None
+                else np.asarray(row_order, dtype=np.int32)
             )
-        # balanced contiguous assignment: shard k takes the k-th slice of
-        # the ordered rows (ids travel with the rows from here on)
-        base, rem = divmod(n, shards)
-        idbuf = np.full((shards, cap_l), cap_g, dtype=np.int32)
-        n_live0 = np.zeros(shards, dtype=np.int32)
-        start = 0
-        for k in range(shards):
-            cnt = base + (1 if k < rem else 0)
-            idbuf[k, :cnt] = order[start : start + cnt]
-            n_live0[k] = cnt
-            start += cnt
-        args = (x, jnp.asarray(idbuf), jnp.asarray(n_live0))
-        if self.model_shards > 1:
-            args += (self._mparams,)
-        compile_program(self._compiled, self._jit, *args)
-        dec, ex, gout, s_f, n_f, n_in_log, reb_log = launch_wave(
-            "sharded", lambda: self._jit(*args)
-        )
-        if self.model_shards > 1:
-            # 2-D outputs carry (data, model) leading axes; everything is
-            # identical across model replicas, so read model coordinate 0
-            dec = np.asarray(dec)[0, 0][:n].astype(bool)
-            ex = np.asarray(ex, dtype=np.int64)[0, 0][:n]
-            gout = np.asarray(gout)[0, 0][:n]
-            s_f = int(np.asarray(s_f)[0, 0])
-            n_f = np.asarray(n_f)[:, 0]
-            n_in_log = np.asarray(n_in_log)[:, 0, :]
-            reb_log = np.asarray(reb_log)[:, 0, :]
-        else:
-            dec = np.asarray(dec)[0][:n].astype(bool)
-            ex = np.asarray(ex, dtype=np.int64)[0][:n]
-            gout = np.asarray(gout)[0][:n]
-            s_f = int(np.asarray(s_f)[0])
-            n_f = np.asarray(n_f)  # (shards,) final live counts
-            n_in_log = np.asarray(n_in_log)  # (shards, S)
-            reb_log = np.asarray(reb_log)  # (shards, S); same across shards
-        stages = plan.stages
-        bn = self.scorer.block_n or self.block_n
-        # a model shard bills its own w_local columns; summed over the
-        # model axis a stage bills w_global = M * ceil(W/M) columns —
-        # the honest cost of a non-dividing split (== W at M=1)
-        w_bill = self._w_global if self.model_shards > 1 else self.dplan.W
-        chunk_stats = []
-        per_shard_scores = np.zeros((shards, s_f), dtype=np.int64)
-        for s in range(s_f):
-            n_in_k = n_in_log[:, s]
-            n_in = int(n_in_k.sum())
-            n_next = int(n_in_log[:, s + 1].sum()) if s + 1 < s_f else int(n_f.sum())
-            # each shard bills the live blocks of ITS slab; empty shards
-            # bill zero (their block guard skipped the whole stage)
-            per_shard_scores[:, s] = (-(-n_in_k // bn)) * bn * w_bill
-            chunk_stats.append(
-                ChunkStat(
-                    t0=stages[s][0],
-                    t1=stages[s][1],
-                    n_in=n_in,
-                    n_exited=n_in - n_next,
-                    scores_computed=int(per_shard_scores[:, s].sum()),
+            if order.shape != (n,):
+                raise ValueError(
+                    f"row_order must be a ({n},)-shaped ordering of the "
+                    f"batch rows, got shape {tuple(order.shape)}"
                 )
+            # balanced contiguous assignment: shard k takes the k-th slice of
+            # the ordered rows (ids travel with the rows from here on)
+            base, rem = divmod(n, shards)
+            idbuf = np.full((shards, cap_l), cap_g, dtype=np.int32)
+            n_live0 = np.zeros(shards, dtype=np.int32)
+            start = 0
+            for k in range(shards):
+                cnt = base + (1 if k < rem else 0)
+                idbuf[k, :cnt] = order[start : start + cnt]
+                n_live0[k] = cnt
+                start += cnt
+            args = (x, jnp.asarray(idbuf), jnp.asarray(n_live0))
+            if self.model_shards > 1:
+                args += (self._mparams,)
+            compile_program(self._compiled, self._jit, *args)
+            dec, ex, gout, s_f, n_f, n_in_log, reb_log = launch_wave(
+                "sharded", lambda: self._jit(*args)
             )
-        self.last_run_info = {
-            "shards": shards,
-            "stages_run": s_f,
-            "per_shard_n_in": n_in_log[:, :s_f].copy(),
-            "per_shard_final_live": n_f.copy(),
-            "per_shard_scores": per_shard_scores,
-            "rebalanced_stages": np.flatnonzero(reb_log[0][:s_f]).tolist(),
-            "model_shards": self.model_shards,
-        }
-        if self.model_shards > 1:
-            m = self.model_shards
-            # per-("data","model")-coordinate attribution: coordinate
-            # (d, j) scored ceil(n_in[d]/bn)*bn rows times ITS w_local
-            # columns at every stage step, and issued exactly ONE
-            # model-axis psum per stage step (the 2-D contract the perf
-            # gate locks)
-            coord = (-(-n_in_log[:, :s_f] // bn)) * bn * self._w_local
-            self.last_run_info.update(
-                mesh_shape=(shards, m),
-                per_coord_scores=np.repeat(coord[:, None, :], m, axis=1),
-                per_coord_psums=np.full((shards, m), s_f, dtype=np.int64),
-                per_coord_stages=np.full((shards, m), s_f, dtype=np.int64),
-            )
+        with TraceAnnotation(tracing.RUN_FETCH):
+            if self.model_shards > 1:
+                # 2-D outputs carry (data, model) leading axes; everything is
+                # identical across model replicas, so read model coordinate 0
+                dec = np.asarray(dec)[0, 0][:n].astype(bool)
+                ex = np.asarray(ex, dtype=np.int64)[0, 0][:n]
+                gout = np.asarray(gout)[0, 0][:n]
+                s_f = int(np.asarray(s_f)[0, 0])
+                n_f = np.asarray(n_f)[:, 0]
+                n_in_log = np.asarray(n_in_log)[:, 0, :]
+                reb_log = np.asarray(reb_log)[:, 0, :]
+            else:
+                dec = np.asarray(dec)[0][:n].astype(bool)
+                ex = np.asarray(ex, dtype=np.int64)[0][:n]
+                gout = np.asarray(gout)[0][:n]
+                s_f = int(np.asarray(s_f)[0])
+                n_f = np.asarray(n_f)  # (shards,) final live counts
+                n_in_log = np.asarray(n_in_log)  # (shards, S)
+                reb_log = np.asarray(reb_log)  # (shards, S); same across shards
+        with TraceAnnotation(tracing.RUN_STATS):
+            stages = plan.stages
+            bn = self.scorer.block_n or self.block_n
+            # a model shard bills its own w_local columns; summed over the
+            # model axis a stage bills w_global = M * ceil(W/M) columns —
+            # the honest cost of a non-dividing split (== W at M=1)
+            w_bill = self._w_global if self.model_shards > 1 else self.dplan.W
+            chunk_stats = []
+            per_shard_scores = np.zeros((shards, s_f), dtype=np.int64)
+            for s in range(s_f):
+                n_in_k = n_in_log[:, s]
+                n_in = int(n_in_k.sum())
+                n_next = int(n_in_log[:, s + 1].sum()) if s + 1 < s_f else int(n_f.sum())
+                # each shard bills the live blocks of ITS slab; empty shards
+                # bill zero (their block guard skipped the whole stage)
+                per_shard_scores[:, s] = (-(-n_in_k // bn)) * bn * w_bill
+                chunk_stats.append(
+                    ChunkStat(
+                        t0=stages[s][0],
+                        t1=stages[s][1],
+                        n_in=n_in,
+                        n_exited=n_in - n_next,
+                        scores_computed=int(per_shard_scores[:, s].sum()),
+                    )
+                )
+            self.last_run_info = {
+                "shards": shards,
+                "stages_run": s_f,
+                "per_shard_n_in": n_in_log[:, :s_f].copy(),
+                "per_shard_final_live": n_f.copy(),
+                "per_shard_scores": per_shard_scores,
+                "rebalanced_stages": np.flatnonzero(reb_log[0][:s_f]).tolist(),
+                "model_shards": self.model_shards,
+            }
+            if self.model_shards > 1:
+                m = self.model_shards
+                # per-("data","model")-coordinate attribution: coordinate
+                # (d, j) scored ceil(n_in[d]/bn)*bn rows times ITS w_local
+                # columns at every stage step, and issued exactly ONE
+                # model-axis psum per stage step (the 2-D contract the perf
+                # gate locks)
+                coord = (-(-n_in_log[:, :s_f] // bn)) * bn * self._w_local
+                self.last_run_info.update(
+                    mesh_shape=(shards, m),
+                    per_coord_scores=np.repeat(coord[:, None, :], m, axis=1),
+                    per_coord_psums=np.full((shards, m), s_f, dtype=np.int64),
+                    per_coord_stages=np.full((shards, m), s_f, dtype=np.int64),
+                )
         return ExecutorResult(
             decisions=dec,
             exit_step=ex,

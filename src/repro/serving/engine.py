@@ -64,13 +64,14 @@ holding whole batches hostage.  Per-request enqueue->decision latency
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import tracing
 from repro.api.backends import BackoffPolicy, DegradationLadder
 from repro.core.executor import CascadePlan, matrix_producer
 from repro.core.qwyc import QWYCModel
@@ -95,7 +96,6 @@ class ServeStats:
     full_cost: float = 0.0
     actual_cost: float = 0.0  # modeled cost at the paper's accounting
     diffs_vs_full: int = 0
-    wall_s: float = 0.0
     # lazy-execution accounting: what was ACTUALLY computed, vs modeled
     scores_computed: int = 0  # base-model scores produced on the serving path
     scores_possible: int = 0  # N * T — the eager full-matrix bill
@@ -523,7 +523,8 @@ class QWYCServer:
             rows_all = jnp.arange(cap, dtype=jnp.int32)
 
             def key_scores(x, n, _s=scorer, _r=rows_all):
-                return _s.fn(x, _r, jnp.int32(0), n)[:, 0]
+                with jax.named_scope(tracing.SORT_KEY):
+                    return _s.fn(x, _r, jnp.int32(0), n)[:, 0]
 
             jitted, compiled = jax.jit(key_scores), set()
 
@@ -553,27 +554,29 @@ class QWYCServer:
         billing plus (for ``sorted-kernel`` with a lazy scorer) the
         sort-key slab, which recomputes stage 0 once more on device.
         """
-        executor, scorer, eager_matrix, key_fn = self._device_state()
-        cap = executor._cap(max(n, self.flush_size))
-        batch, ordered = self._eager_or_raw(xb, eager_matrix)
-        row_order = None
-        key_scores = 0
-        prepared = False
-        if self.backend == "sorted-kernel":
-            if eager_matrix:
-                col0 = ordered[:, 0]
-            else:
-                # prepare + pad ONCE; the key computation and the executor
-                # share the same device operand (prepared=True below)
+        with TraceAnnotation(tracing.FLUSH_PREPARE):
+            executor, scorer, eager_matrix, key_fn = self._device_state()
+            batch, ordered = self._eager_or_raw(xb, eager_matrix)
+            # a lazy sorted-kernel flush prepares + pads ONCE; the key
+            # computation and the executor share the same device operand
+            prepared = self.backend == "sorted-kernel" and not eager_matrix
+            if prepared:
+                cap = executor._cap(max(n, self.flush_size))
                 batch = scorer.prepare(batch)
                 if batch.shape[0] < cap:
                     pad = ((0, cap - batch.shape[0]),) + ((0, 0),) * (batch.ndim - 1)
                     batch = jnp.pad(batch, pad)
-                prepared = True
-                col0 = np.asarray(key_fn(batch, n))[:n]
-                kb = scorer.block_n or self.block_n
-                key_scores = -(-n // kb) * kb * scorer.width
-            row_order = np.argsort(col0, kind="stable")
+        row_order = None
+        key_scores = 0
+        if self.backend == "sorted-kernel":
+            with TraceAnnotation(tracing.FLUSH_SORT_KEY):
+                if eager_matrix:
+                    col0 = ordered[:, 0]
+                else:
+                    col0 = np.asarray(key_fn(batch, n))[:n]
+                    kb = scorer.block_n or self.block_n
+                    key_scores = -(-n // kb) * kb * scorer.width
+                row_order = np.argsort(col0, kind="stable")
         res = executor.run(
             batch, n, row_order=row_order, capacity=self.flush_size,
             prepared=prepared,
@@ -615,43 +618,47 @@ class QWYCServer:
     def flush(self) -> list[dict]:
         if not self._queue:
             return []
-        t_start = time.time()
-        xb = np.stack(self._queue)
-        seqs = self._qseqs
-        self._queue = []
-        self._qseqs = []
-        n = xb.shape[0]
+        with TraceAnnotation(
+            tracing.FLUSH, index=self.stats.n_batches, n=len(self._queue)
+        ):
+            with TraceAnnotation(tracing.FLUSH_STACK):
+                xb = np.stack(self._queue)
+                seqs = self._qseqs
+                self._queue = []
+                self._qseqs = []
+            n = xb.shape[0]
 
-        # the wave ladder: retry the rung with backoff, then fall one
-        # rung and re-run the SAME batch — no request is lost to a fault
-        while True:
-            try:
-                if self.device:
-                    res, ordered, device_billed = self.ladder.attempt(
-                        "wave", self.exec.name,
-                        lambda: self._run_device(xb, n),
-                    )
-                    # the host chunk producer (escape hatch) doubles as
-                    # the unbilled audit path; _producers builds the same
-                    # wrapper the host path uses
-                    audit_read = (
-                        self._producers(xb)[0]
-                        if self.chunk_score_fn is not None
-                        else None
-                    )
-                else:
-                    res, ordered, audit_read, device_billed = (
-                        self.ladder.attempt(
+            # the wave ladder: retry the rung with backoff, then fall one
+            # rung and re-run the SAME batch — no request is lost to a fault
+            while True:
+                try:
+                    if self.device:
+                        res, ordered, device_billed = self.ladder.attempt(
                             "wave", self.exec.name,
-                            lambda: self._run_host(xb, n),
+                            lambda: self._run_device(xb, n),
                         )
-                    )
-                break
-            except RuntimeError as e:
-                self._fall_rung(e)
-        return self._finish_flush(
-            t_start, xb, n, res, ordered, audit_read, device_billed, seqs
-        )
+                        # the host chunk producer (escape hatch) doubles as
+                        # the unbilled audit path; _producers builds the same
+                        # wrapper the host path uses
+                        audit_read = (
+                            self._producers(xb)[0]
+                            if self.chunk_score_fn is not None
+                            else None
+                        )
+                    else:
+                        res, ordered, audit_read, device_billed = (
+                            self.ladder.attempt(
+                                "wave", self.exec.name,
+                                lambda: self._run_host(xb, n),
+                            )
+                        )
+                    break
+                except RuntimeError as e:
+                    self._fall_rung(e)
+            with TraceAnnotation(tracing.FLUSH_FINISH):
+                return self._finish_flush(
+                    xb, n, res, ordered, audit_read, device_billed, seqs
+                )
 
     def _run_host(self, xb: np.ndarray, n: int):
         """Host stage-loop path for one batch ->
@@ -691,7 +698,7 @@ class QWYCServer:
         return res, ordered, audit_read, None
 
     def _finish_flush(
-        self, t_start, xb, n, res, ordered, audit_read, device_billed, seqs
+        self, xb, n, res, ordered, audit_read, device_billed, seqs
     ) -> list[dict]:
         """Audit, result assembly and stats — shared by host & device paths.
 
@@ -773,7 +780,6 @@ class QWYCServer:
             # unaudited: survivors' decision IS the full decision (0 diffs);
             # early-exit rows are unknown and intentionally not guessed at
             pass
-        st.wall_s += time.time() - t_start
         return out
 
     def _merge_results(self) -> list[dict]:
@@ -786,7 +792,8 @@ class QWYCServer:
 
     def drain(self) -> list[dict]:
         self.flush()
-        return self._merge_results()
+        with TraceAnnotation(tracing.DRAIN):
+            return self._merge_results()
 
 
 class StreamingServer(QWYCServer):
@@ -887,7 +894,6 @@ class StreamingServer(QWYCServer):
         """Stream one window (possibly partial) of queued requests."""
         if not self._squeue:
             return []
-        t_start = time.time()
         wave, self._squeue = (
             self._squeue[: self.window],
             self._squeue[self.window:],
@@ -922,9 +928,7 @@ class StreamingServer(QWYCServer):
         audit_read = (
             self._producers(xb)[0] if self.chunk_score_fn is not None else None
         )
-        out = self._finish_flush(
-            t_start, xb, n, res, ordered, audit_read, billed, seqs
-        )
+        out = self._finish_flush(xb, n, res, ordered, audit_read, billed, seqs)
         self.stream_results.append(res)
         st = self.stats
         st.admitted_rows += n

@@ -1,0 +1,47 @@
+"""Names of the served path's profiler spans and device scopes.
+
+Host spans are ``jax.profiler.TraceAnnotation``s: they cost well under a
+microsecond each while no profiler runs, and while one runs they land in
+its trace on the same clock as the device's operation events.  Device
+scopes are ``jax.named_scope``s: they exist only while a program is
+traced, end up in its ops' ``op_name`` metadata and cost nothing at run
+time.  Every name starts with ``qwyc.``; where scopes nest, an op belongs
+to the innermost one.
+
+Host spans, outermost first:
+
+* ``FLUSH``: one non-empty ``QWYCServer.flush`` (metadata: ``index``, the
+  flush's number on its server, and ``n``, its rows).  Its phases:
+  ``FLUSH_STACK`` (stacking the queue), ``FLUSH_PREPARE`` (executor state,
+  the operand's upload and padding to capacity), ``FLUSH_SORT_KEY`` (the
+  sorted-kernel policy's key program, its read-back and the host sort),
+  ``RUN_DISPATCH`` / ``RUN_FETCH`` / ``RUN_STATS`` (inside an on-device
+  executor's ``run``: building the row buffers and launching the program;
+  every blocking read of its results, which includes waiting for the
+  device; the per-stage counts) and ``FLUSH_FINISH`` (audit, per-row
+  results, server statistics).
+* ``DRAIN``: ``QWYCServer.drain``'s merge of results.
+* ``COMPILE``: a device program lowered and compiled on a live server.
+
+Device scopes: ``SCORE_DECIDE`` (a stage's scoring and decide kernels),
+``COMPACT`` (the rest of a stage: row gathers, exit scatters, survivor
+repacking), ``FINALIZE`` (after the stage loop), ``SORT_KEY`` (the sort-key
+program) and ``COLLECTIVE`` (the sharded program's all-gathers and psums).
+"""
+
+FLUSH = "qwyc.flush"
+FLUSH_STACK = "qwyc.flush.stack"
+FLUSH_PREPARE = "qwyc.flush.prepare"
+FLUSH_SORT_KEY = "qwyc.flush.sort_key"
+FLUSH_FINISH = "qwyc.flush.finish"
+RUN_DISPATCH = "qwyc.run.dispatch"
+RUN_FETCH = "qwyc.run.fetch"
+RUN_STATS = "qwyc.run.stats"
+DRAIN = "qwyc.drain"
+COMPILE = "qwyc.compile"
+
+SCORE_DECIDE = "qwyc.score_decide"
+COMPACT = "qwyc.compact"
+FINALIZE = "qwyc.finalize"
+SORT_KEY = "qwyc.sort_key"
+COLLECTIVE = "qwyc.collective"
