@@ -127,6 +127,7 @@ class ExecutorResult:
     chunk_stats: list[ChunkStat]
     scores_computed: int  # producer scores actually requested
     scores_possible: int  # N * T — what the eager full-matrix path pays
+    device_reads: int = 0  # blocking device->host reads the run made
 
     @property
     def mean_models(self) -> float:

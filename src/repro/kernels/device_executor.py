@@ -146,6 +146,19 @@ def launch_wave(executor_name: str, fn):
         ) from e
 
 
+def pad_rows(x, cap: int):
+    """``x`` with zero rows appended up to ``cap`` rows.  A host array is
+    padded on the host (``numpy``), so it crosses to the device once at
+    full capacity and no device program is built per row count; a device
+    array is padded on the device."""
+    if not isinstance(x, jax.Array):
+        x = np.asarray(x)
+    if x.shape[0] >= cap:
+        return x
+    pad = ((0, cap - x.shape[0]),) + ((0, 0),) * (x.ndim - 1)
+    return jnp.pad(x, pad) if isinstance(x, jax.Array) else np.pad(x, pad)
+
+
 def check_batch_finite(batch, n: int) -> None:
     """Reject non-finite rows before they reach a device program.
 
@@ -179,6 +192,7 @@ __all__ = [
     "DeviceExecutor",
     "group_topk_rows",
     "matrix_stage_scorer",
+    "pad_rows",
     "tree_stage_scorer",
     "lattice_stage_scorer",
     "stream_occupancy",
@@ -932,7 +946,25 @@ class DeviceExecutor:
                 jnp.take(g, rows_f, axis=0) >= jnp.float32(self.dplan.plan.beta),
                 mode="drop",
             )
-        return dec, ex, g, s_f, n_f, n_in_log
+            # one int32 buffer out, so the host reads it in one transfer
+            # (unpacked by _unpack)
+            return jnp.concatenate([
+                dec.astype(jnp.int32),
+                ex,
+                jax.lax.bitcast_convert_type(g, jnp.int32),
+                jnp.stack([s_f, n_f]),
+                n_in_log,
+            ])
+
+    @staticmethod
+    def _unpack(buf: np.ndarray, cap: int, n: int):
+        """``_program``'s packed results -> (dec, ex, g, s_f, n_f,
+        n_in_log), each row array cut to the batch's ``n`` rows."""
+        dec = buf[:n].astype(bool)
+        ex = buf[cap : cap + n].astype(np.int64)
+        g = buf[2 * cap : 2 * cap + n].view(np.float32)
+        s_f, n_f = int(buf[3 * cap]), int(buf[3 * cap + 1])
+        return dec, ex, g, s_f, n_f, buf[3 * cap + 2 :]
 
     def run(
         self,
@@ -947,8 +979,12 @@ class DeviceExecutor:
         ``batch`` is whatever the scorer's ``prepare`` consumes (feature
         matrix for the tree/lattice scorers, a cascade-ordered score
         matrix for the matrix scorer).  ``row_order`` is the initial
-        active-set ordering (the sorted backend's sort permutation);
-        results always come back scattered to absolute row indices.
+        active-set ordering (the sorted backend's sort permutation): a
+        host ``(n,)`` ordering, or the ``(cap,)`` int32 rows buffer
+        already built on the device (the ordering, then ``cap`` on every
+        lane past ``n``), which the stage loop takes without a read.
+        Results always come back scattered to absolute row indices, in
+        one blocking read.
         ``capacity`` pins the buffer size: a caller flushing variable
         batch sizes (the server's final partial flush) passes its max
         batch size so every flush reuses the one compiled trace.
@@ -971,28 +1007,32 @@ class DeviceExecutor:
             if self.check_finite:
                 check_batch_finite(batch, n)
             cap = self._cap(max(n, capacity or 0))
-            x = self._cast_operand(batch if prepared else self.scorer.prepare(batch))
-            if x.shape[0] < cap:
-                x = jnp.pad(x, ((0, cap - x.shape[0]),) + ((0, 0),) * (x.ndim - 1))
-            rows = (
-                np.arange(n, dtype=np.int32)
-                if row_order is None
-                else np.asarray(row_order, dtype=np.int32)
-            )
-            assert rows.shape == (n,)
-            rows_init = np.full(cap, cap, dtype=np.int32)
-            rows_init[:n] = rows
-            args = (x, jnp.asarray(rows_init), n)
+            if not prepared:
+                batch = self.scorer.prepare(pad_rows(batch, cap))
+            x = pad_rows(self._cast_operand(batch), cap)
+            if isinstance(row_order, jax.Array):
+                if row_order.shape != (cap,) or row_order.dtype != jnp.int32:
+                    raise ValueError(
+                        f"a device row_order is the ({cap},) int32 rows "
+                        f"buffer, got {row_order.shape} {row_order.dtype}"
+                    )
+                rows_init = row_order
+            else:
+                rows = (
+                    np.arange(n, dtype=np.int32)
+                    if row_order is None
+                    else np.asarray(row_order, dtype=np.int32)
+                )
+                assert rows.shape == (n,)
+                rows_init = np.full(cap, cap, dtype=np.int32)
+                rows_init[:n] = rows
+                rows_init = jnp.asarray(rows_init)
+            args = (x, rows_init, n)
             compile_program(self._compiled, self._jit, *args)
-            dec, ex, g, s_f, n_f, n_in_log = launch_wave(
-                "device", lambda: self._jit(*args)
-            )
+            out = launch_wave("device", lambda: self._jit(*args))
         with TraceAnnotation(tracing.RUN_FETCH):
-            dec = np.asarray(dec)[:n]
-            ex = np.asarray(ex, dtype=np.int64)[:n]
-            g = np.asarray(g)[:n]
-            s_f, n_f = int(s_f), int(n_f)
-            n_in_log = np.asarray(n_in_log)
+            # one blocking read of the packed results
+            dec, ex, g, s_f, n_f, n_in_log = self._unpack(np.asarray(out), cap, n)
         with TraceAnnotation(tracing.RUN_STATS):
             stages = plan.stages
             # bill at the SCORER's kernel block size (the granularity its
@@ -1014,12 +1054,13 @@ class DeviceExecutor:
                     )
                 )
         return ExecutorResult(
-            decisions=dec.astype(bool),
+            decisions=dec,
             exit_step=ex,
             g_final=g,
             chunk_stats=chunk_stats,
             scores_computed=sum(c.scores_computed for c in chunk_stats),
             scores_possible=n * T,
+            device_reads=1,
         )
 
     # -- streaming admission (continuous batching, DESIGN.md §8) --------

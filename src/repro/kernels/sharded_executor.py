@@ -87,6 +87,7 @@ from repro.kernels.device_executor import (
     compile_program,
     group_topk_rows,
     launch_wave,
+    pad_rows,
     repack_state,
     stream_occupancy,
 )
@@ -573,7 +574,9 @@ class ShardedDeviceExecutor:
         a sorted order keeps easy rows clustered — the rebalance step
         exists exactly because such slices drain unevenly), ``capacity``
         pins the GLOBAL buffer size so variable flush sizes reuse one
-        trace, ``prepared=True`` skips ``scorer.prepare``.
+        trace, ``prepared=True`` skips ``scorer.prepare``.  The shards
+        are dealt on the host, so a device rows buffer costs one read
+        more.
         """
         plan = self.dplan.plan
         T = plan.T
@@ -603,9 +606,15 @@ class ShardedDeviceExecutor:
         with TraceAnnotation(tracing.RUN_DISPATCH):
             cap_l = self._cap_local(max(n, capacity or 0))
             cap_g = shards * cap_l
-            x = self._cast_operand(batch if prepared else self.scorer.prepare(batch))
-            if x.shape[0] < cap_g:
-                x = jnp.pad(x, ((0, cap_g - x.shape[0]),) + ((0, 0),) * (x.ndim - 1))
+            if not prepared:
+                batch = self.scorer.prepare(pad_rows(batch, cap_g))
+            x = pad_rows(self._cast_operand(batch), cap_g)
+            reads = 1
+            if isinstance(row_order, jax.Array):
+                # a device rows buffer (the sort-key program's): the
+                # shards are dealt on the host, so it is read once here
+                row_order = np.asarray(row_order)[:n]
+                reads += 1
             order = (
                 np.arange(n, dtype=np.int32)
                 if row_order is None
@@ -631,28 +640,27 @@ class ShardedDeviceExecutor:
             if self.model_shards > 1:
                 args += (self._mparams,)
             compile_program(self._compiled, self._jit, *args)
-            dec, ex, gout, s_f, n_f, n_in_log, reb_log = launch_wave(
-                "sharded", lambda: self._jit(*args)
-            )
+            out = launch_wave("sharded", lambda: self._jit(*args))
         with TraceAnnotation(tracing.RUN_FETCH):
+            # one blocking read: every copy starts before the first wait
+            dec, ex, gout, s_f, n_f, n_in_log, reb_log = jax.device_get(out)
             if self.model_shards > 1:
                 # 2-D outputs carry (data, model) leading axes; everything is
                 # identical across model replicas, so read model coordinate 0
-                dec = np.asarray(dec)[0, 0][:n].astype(bool)
-                ex = np.asarray(ex, dtype=np.int64)[0, 0][:n]
-                gout = np.asarray(gout)[0, 0][:n]
-                s_f = int(np.asarray(s_f)[0, 0])
-                n_f = np.asarray(n_f)[:, 0]
-                n_in_log = np.asarray(n_in_log)[:, 0, :]
-                reb_log = np.asarray(reb_log)[:, 0, :]
+                dec = dec[0, 0][:n].astype(bool)
+                ex = ex[0, 0][:n].astype(np.int64)
+                gout = gout[0, 0][:n]
+                s_f = int(s_f[0, 0])
+                n_f = n_f[:, 0]
+                n_in_log = n_in_log[:, 0, :]
+                reb_log = reb_log[:, 0, :]
             else:
-                dec = np.asarray(dec)[0][:n].astype(bool)
-                ex = np.asarray(ex, dtype=np.int64)[0][:n]
-                gout = np.asarray(gout)[0][:n]
-                s_f = int(np.asarray(s_f)[0])
-                n_f = np.asarray(n_f)  # (shards,) final live counts
-                n_in_log = np.asarray(n_in_log)  # (shards, S)
-                reb_log = np.asarray(reb_log)  # (shards, S); same across shards
+                dec = dec[0][:n].astype(bool)
+                ex = ex[0][:n].astype(np.int64)
+                gout = gout[0][:n]
+                s_f = int(s_f[0])
+                # n_f: (shards,) final live counts; n_in_log and reb_log:
+                # (shards, S), reb_log the same across shards
         with TraceAnnotation(tracing.RUN_STATS):
             stages = plan.stages
             bn = self.scorer.block_n or self.block_n
@@ -708,6 +716,7 @@ class ShardedDeviceExecutor:
             chunk_stats=chunk_stats,
             scores_computed=sum(c.scores_computed for c in chunk_stats),
             scores_possible=n * T,
+            device_reads=reads,
         )
 
     # -- grouped (ranking) decide, data-parallel over groups ------------

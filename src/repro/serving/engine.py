@@ -77,9 +77,11 @@ from repro.core.executor import CascadePlan, matrix_producer
 from repro.core.qwyc import QWYCModel
 from repro.kernels import ops
 from repro.kernels.device_executor import (
+    BoundScorer,
     DevicePlan,
     compile_program,
     matrix_stage_scorer,
+    pad_rows,
 )
 from repro.serving.watchdog import DriftWatchdog, WatchdogConfig, widen_plan
 
@@ -120,6 +122,9 @@ class ServeStats:
     watchdog_stat: float = 0.0  # current sequential llr
     watchdog_margin: float = 0.0  # threshold widening in force next flush
     watchdog_recovery_step: int | None = None  # flush index of last recovery
+    # blocking device->host reads the flushes made (1 a flush on one
+    # device); like the guarded counters, outside the perf gate's baseline
+    device_reads: int = 0
 
     @property
     def mean_models(self) -> float:
@@ -167,6 +172,32 @@ class ServeStats:
     @property
     def latency_p99(self) -> float:
         return self.latency_pct(99)
+
+
+def sort_key_program(scorer: BoundScorer):
+    """The sorted-kernel policy's key program, ``(x, n) -> rows``.
+
+    The key is the first cascade model's scores, computed on the device
+    from the same stage-0 slab the loop body uses.  The program returns
+    the stage loop's initial ``(cap,)`` rows buffer, so the permutation
+    never leaves the device: the ``n`` rows in
+    ``np.argsort(key, kind="stable")`` order, then ``cap`` on every lane
+    past ``n``."""
+
+    def key_rows(x, n):
+        with jax.named_scope(tracing.SORT_KEY):
+            cap = x.shape[0]
+            lane = jnp.arange(cap, dtype=jnp.int32)
+            key = scorer.fn(x, lane, jnp.int32(0), n)[:, 0]
+            # stable on (padding lane, key): padding lanes sort last
+            _, _, perm = jax.lax.sort(
+                ((lane >= n).astype(jnp.int32), key, lane),
+                num_keys=2,
+                is_stable=True,
+            )
+            return jnp.where(lane < n, perm, cap)
+
+    return key_rows
 
 
 class QWYCServer:
@@ -517,16 +548,7 @@ class QWYCServer:
                     f"scorers like {type(self.scorer_template).__name__} "
                     "serve under the 'kernel' policy"
                 )
-            # sort key = first cascade model's scores, computed on
-            # device from the same stage-0 slab the loop body uses
-            cap = executor._cap(self.flush_size)
-            rows_all = jnp.arange(cap, dtype=jnp.int32)
-
-            def key_scores(x, n, _s=scorer, _r=rows_all):
-                with jax.named_scope(tracing.SORT_KEY):
-                    return _s.fn(x, _r, jnp.int32(0), n)[:, 0]
-
-            jitted, compiled = jax.jit(key_scores), set()
+            jitted, compiled = jax.jit(sort_key_program(scorer)), set()
 
             def key_fn(x, n):
                 # a refused program raises here, past the wave ladder
@@ -557,26 +579,26 @@ class QWYCServer:
         with TraceAnnotation(tracing.FLUSH_PREPARE):
             executor, scorer, eager_matrix, key_fn = self._device_state()
             batch, ordered = self._eager_or_raw(xb, eager_matrix)
-            # a lazy sorted-kernel flush prepares + pads ONCE; the key
-            # computation and the executor share the same device operand
-            prepared = self.backend == "sorted-kernel" and not eager_matrix
+            # padded on the host to the flush capacity: one upload, and
+            # one program for every flush size
+            batch = pad_rows(batch, executor._cap(max(n, self.flush_size)))
+            # a lazy sorted-kernel flush prepares ONCE; the key program
+            # and the executor share the same device operand
+            prepared = key_fn is not None
             if prepared:
-                cap = executor._cap(max(n, self.flush_size))
                 batch = scorer.prepare(batch)
-                if batch.shape[0] < cap:
-                    pad = ((0, cap - batch.shape[0]),) + ((0, 0),) * (batch.ndim - 1)
-                    batch = jnp.pad(batch, pad)
         row_order = None
         key_scores = 0
         if self.backend == "sorted-kernel":
             with TraceAnnotation(tracing.FLUSH_SORT_KEY):
                 if eager_matrix:
-                    col0 = ordered[:, 0]
+                    row_order = np.argsort(ordered[:, 0], kind="stable")
                 else:
-                    col0 = np.asarray(key_fn(batch, n))[:n]
+                    # launched, not waited on: the rows buffer stays on
+                    # the device for the stage loop
+                    row_order = key_fn(batch, n)
                     kb = scorer.block_n or self.block_n
                     key_scores = -(-n // kb) * kb * scorer.width
-                row_order = np.argsort(col0, kind="stable")
         res = executor.run(
             batch, n, row_order=row_order, capacity=self.flush_size,
             prepared=prepared,
@@ -655,6 +677,7 @@ class QWYCServer:
                     break
                 except RuntimeError as e:
                     self._fall_rung(e)
+            self.stats.device_reads += res.device_reads
             with TraceAnnotation(tracing.FLUSH_FINISH):
                 return self._finish_flush(
                     xb, n, res, ordered, audit_read, device_billed, seqs

@@ -13,13 +13,13 @@ Host spans, outermost first:
 * ``FLUSH``: one non-empty ``QWYCServer.flush`` (metadata: ``index``, the
   flush's number on its server, and ``n``, its rows).  Its phases:
   ``FLUSH_STACK`` (stacking the queue), ``FLUSH_PREPARE`` (executor state,
-  the operand's upload and padding to capacity), ``FLUSH_SORT_KEY`` (the
-  sorted-kernel policy's key program, its read-back and the host sort),
-  ``RUN_DISPATCH`` / ``RUN_FETCH`` / ``RUN_STATS`` (inside an on-device
-  executor's ``run``: building the row buffers and launching the program;
-  every blocking read of its results, which includes waiting for the
-  device; the per-stage counts) and ``FLUSH_FINISH`` (audit, per-row
-  results, server statistics).
+  padding to capacity on the host, the operand's upload),
+  ``FLUSH_SORT_KEY`` (launching the sorted-kernel policy's key program,
+  which sorts on the device), ``RUN_DISPATCH`` / ``RUN_FETCH`` /
+  ``RUN_STATS`` (inside an on-device executor's ``run``: building the row
+  buffers and launching the program; the blocking read of its results,
+  which includes waiting for the device; the per-stage counts) and
+  ``FLUSH_FINISH`` (audit, per-row results, server statistics).
 * ``DRAIN``: ``QWYCServer.drain``'s merge of results.
 * ``COMPILE``: a device program lowered and compiled on a live server.
 

@@ -14,6 +14,7 @@ import: only one process may load the TPU compiler library at a time.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -220,3 +221,28 @@ def test_full_cascade_decide_compiles(one_chip):
         one_chip,
         ((N, T), F32), ((T,), F32), ((T,), F32),
     )
+
+
+@pytest.mark.parametrize("cap", [256, N])
+def test_sort_key_program_compiles(one_chip, cap):
+    """The sorted-kernel policy's key program: the stage-0 tree kernel
+    and the on-device sort that builds the stage loop's rows buffer, at
+    the online and the offline flush capacities."""
+    from repro.api import TreeScorer
+    from repro.serving.engine import sort_key_program
+
+    rng = np.random.default_rng(2)
+    dplan = DevicePlan.from_plan(dataclasses.replace(_dplan().plan, lead_t=1))
+    scorer = TreeScorer(
+        rng.integers(0, D, size=(T, DEPTH)).astype(np.int32),
+        rng.uniform(size=(T, DEPTH)).astype(np.float32),
+        rng.normal(size=(T, 1 << DEPTH)).astype(np.float32),
+        block_n=BN, interpret=False,
+    ).bind(dplan)
+    args = [
+        jax.ShapeDtypeStruct((cap, D), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), I32, sharding=one_chip),
+    ]
+    text = jax.jit(sort_key_program(scorer)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"\bsort\(", text)

@@ -168,7 +168,8 @@ def test_device_program_scopes(megakernel):
 
 
 def test_sort_key_program_scope(monkeypatch):
-    """The sorted-kernel policy's key program runs under ``qwyc.sort_key``."""
+    """The sorted-kernel policy's key program, its sort included, runs
+    under ``qwyc.sort_key``."""
     built = []
 
     def record(compiled, jitted, *args, **kw):
@@ -183,7 +184,9 @@ def test_sort_key_program_scope(monkeypatch):
         srv.submit(row)
     assert len(srv.drain()) == 10
     (jitted, args), = built
-    assert _has(_op_names(jitted.lower(*args)), r"^jit\(key_scores\)/qwyc\.sort_key/")
+    names = _op_names(jitted.lower(*args))
+    assert _has(names, r"^jit\(key_rows\)/qwyc\.sort_key/")
+    assert _has(names, r"^jit\(key_rows\)/qwyc\.sort_key/sort")
 
 
 @pytest.mark.parametrize("megakernel", [True, False])
