@@ -89,17 +89,51 @@ def setup_compile_cache() -> str:
 # --------------------------------------------------------------- set-up
 
 
-def _world(cfg: dict):
+def _world(ens, cfg: dict):
+    """The rows the cell trains, calibrates and serves on: the kind's own
+    ``world(cfg)`` where its module has one, else the synthetic world."""
+    if hasattr(ens, "world"):
+        return ens.world(cfg)
     return worldgen.make_world(cfg["world"], int(cfg["train_rows"]), int(cfg["pool_rows"]))
+
+
+def fit_settings(ens, cfg: dict, T: int) -> dict:
+    """Keyword arguments for ``api.fit`` beyond beta, alpha and mode: the
+    kind's ``fit_settings(cfg, T)`` (order, costs), else none."""
+    return dict(ens.fit_settings(cfg, T)) if hasattr(ens, "fit_settings") else {}
+
+
+DEFAULT_BAND = (
+    reference.REL_TOL,
+    0.01,
+    "float32 program against a float32 reference: a row within 1e-5 x sum|f| of a "
+    "threshold is not compared",
+)
+
+
+def rounding_band(ens, cfg: dict) -> tuple[float, float, str]:
+    """(rel_tol, max_ambiguous_share, why): the band around a threshold
+    within which a row is not compared, and the share of answered rows
+    that may fall in it; the kind's ``rounding_band(cfg)``, else
+    ``DEFAULT_BAND``."""
+    if hasattr(ens, "rounding_band"):
+        rel_tol, share, why = ens.rounding_band(cfg)
+        return float(rel_tol), float(share), str(why)
+    return DEFAULT_BAND
+
+
+def artifact_path(cell: Cell) -> Path:
+    """Where the fitted cascade is kept, keyed by the configuration file's
+    content."""
+    key = hashlib.sha256(cell.config_bytes).hexdigest()[:16]
+    return CACHE / "fitted" / f"{cell.config['name']}-{key}.npz"
 
 
 def fitted_artifact(cell: Cell, ens) -> tuple[dict, dict, bool]:
     """(params, plan, built): the trained ensemble and its fitted plan,
-    built on a checkout's first run and loaded after, keyed by the
-    configuration file's content."""
+    built on a checkout's first run and loaded after."""
     cfg = cell.config
-    key = hashlib.sha256(cell.config_bytes).hexdigest()[:16]
-    path = CACHE / "fitted" / f"{cfg['name']}-{key}.npz"
+    path = artifact_path(cell)
     if path.exists():
         z = np.load(path)
         params = {k[6:]: z[k] for k in z.files if k.startswith("param.")}
@@ -107,10 +141,13 @@ def fitted_artifact(cell: Cell, ens) -> tuple[dict, dict, bool]:
         return params, plan, False
     from repro import api
 
-    w = _world(cfg)
+    w = _world(ens, cfg)
     params, beta = ens.train(cfg, w)
     F = ens.scores(params, w.x_train)
-    model = api.fit(F, beta=beta, alpha=float(cfg["alpha"]), mode=cfg["mode"]).model
+    model = api.fit(
+        F, beta=beta, alpha=float(cfg["alpha"]), mode=cfg["mode"],
+        **fit_settings(ens, cfg, F.shape[1]),
+    ).model
     plan = {
         "order": np.asarray(model.order), "eps_pos": np.asarray(model.eps_pos),
         "eps_neg": np.asarray(model.eps_neg), "costs": np.asarray(model.costs),
@@ -156,7 +193,7 @@ def open_session(cell: Cell) -> Session:
     )
     compiled = fitted.compile(cfg["backend"], scorer=ens.program_scorer(params))
     srv = compiled.serve(batch_size=int(cfg["batch_size"]))
-    return Session(cell, ens, params, plan, _world(cfg), built, compiled, srv)
+    return Session(cell, ens, params, plan, _world(ens, cfg), built, compiled, srv)
 
 
 def warm_up(sess: Session) -> None:
@@ -351,7 +388,7 @@ def open_loop(sess: Session, seed: int, seconds: float, spans: Spans) -> dict:
     ex = np.zeros(n, np.int64)
     answered = np.zeros(n, bool)
     done = np.zeros(n)
-    lag, flush_walls, flush_rows, backlog, flush_max = [], [], [], [], []
+    flush_walls, flush_rows, backlog, flush_max = [], [], [], []
     i = 0
     with spans("window"):
         t0 = time.perf_counter()
@@ -361,7 +398,6 @@ def open_loop(sess: Session, seed: int, seconds: float, spans: Spans) -> dict:
                 with spans("wait"):
                     time.sleep(due[i] - now)
                 now = time.perf_counter() - t0
-                lag.append(now - due[i])
             j = i
             with spans("submit"):
                 while j < n and j - i < cap and due[j] <= now:
@@ -387,7 +423,7 @@ def open_loop(sess: Session, seed: int, seconds: float, spans: Spans) -> dict:
     return {
         "elapsed_s": elapsed, "idx": idx, "dec": dec, "ex": ex,
         "answered": answered, "latency_s": done - due,
-        "generator_lag_s": np.asarray(lag), "flush_wall_s": np.asarray(flush_walls),
+        "flush_wall_s": np.asarray(flush_walls),
         "flush_rows": np.asarray(flush_rows),
         "backlog": np.asarray(backlog), "flush_max_exit": np.asarray(flush_max),
         "n_flush": len(flush_walls),
@@ -402,14 +438,16 @@ DRIVERS = {"closed": closed_loop, "open": open_loop}
 
 def reference_verdicts(sess: Session, lower: bool = False):
     """(decisions, exit steps, ambiguous) of the plain cascade for every
-    pool row; ``lower`` computes the control, with the weights rounded to
-    the precision below the configuration's."""
+    pool row, with the kind's rounding band; ``lower`` computes the
+    control, with the weights rounded to the precision below the
+    configuration's."""
     params = sess.ens.lower_precision(sess.params) if lower else sess.params
     plan = sess.plan
     scores = sess.ens.scores(params, sess.world.pool)
+    rel_tol, _, _ = rounding_band(sess.ens, sess.cell.config)
     return reference.cascade(
         scores[:, np.asarray(plan["order"])], plan["eps_pos"], plan["eps_neg"],
-        float(plan["beta"]),
+        float(plan["beta"]), rel_tol=rel_tol,
     )
 
 
@@ -419,10 +457,12 @@ def check(sess: Session, run: dict) -> dict:
     ``mismatched_rows``: served verdicts or exit steps that differ from the
     plain cascade's, over every request answered in the window, leaving
     out rows the reference marks ambiguous.  Outside that rounding band
-    the comparison is exact, so its limit is 0.  ``unanswered``: requests with
-    no verdict.  ``calib_disagreement``: the plan's disagreement with the
-    full ensemble on its calibration rows, against the configuration's
-    ``alpha``.
+    the comparison is exact, so its limit is 0.  ``ambiguous_share``: the
+    rows so left out over the rows answered, against the band's
+    ``max_ambiguous_share``, so that a wide band cannot empty the
+    comparison.  ``unanswered``: requests with no verdict.
+    ``calib_disagreement``: the plan's disagreement with the full ensemble
+    on its calibration rows, against the configuration's ``alpha``.
     """
     ens, params, plan, cfg = sess.ens, sess.params, sess.plan, sess.cell.config
     order = np.asarray(plan["order"])
@@ -439,8 +479,10 @@ def check(sess: Session, run: dict) -> dict:
     disagree = float(np.mean(cdec != reference.full_decisions(calib, beta)))
     run["ambiguous_rows"] = int(amb.sum())
     run["mismatched"] = bad
+    _, max_share, _ = rounding_band(ens, cfg)
     return {
         "mismatched_rows": {"value": int(bad.sum()), "limit": 0},
+        "ambiguous_share": {"value": int(amb.sum()) / max(amb.size, 1), "limit": max_share},
         "unanswered": {"value": int((~answered).sum()), "limit": 0},
         "calib_disagreement": {"value": disagree, "limit": float(cfg["alpha"])},
     }
@@ -523,6 +565,8 @@ def run_cell(
         + (f", built the fitted cascade (first run in this checkout)" if sess.built else "")
     )
     log(phases)
+    log("rounding band: rel_tol {}, at most {} of answered rows; {}".format(
+        *rounding_band(sess.ens, cell.config)))
     log(f"programs built in the window: {built}; gc in the window: {gcp.summary()}")
     if "latency_s" in run:
         q = np.percentile(run["latency_s"], [50, 90, 95, 99, 99.9, 100]) * 1e3
@@ -531,8 +575,8 @@ def run_cell(
     d0 = devices[0]
     ctx = SimpleNamespace(
         cell=cell, cfg=cell.config, mix=cell.mix, chips=cell.chips, ens=sess.ens,
-        features=int(sess.world.pool.shape[1]), run=run, setup_s=setup_s, summary=None,
-        peak=None,
+        order=np.asarray(sess.plan["order"]), features=int(sess.world.pool.shape[1]),
+        run=run, setup_s=setup_s, summary=None, peak=None,
     )
     device = {"platform": d0.platform, "kind": d0.device_kind, "count": len(devices),
               "memory_peak_bytes": mem}

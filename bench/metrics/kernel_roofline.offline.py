@@ -11,6 +11,8 @@ def read(ctx):
     busy = sum(s.busy_s) if s is not None else 0.0
     if busy <= 0 or run["ex"].size == 0:
         return None
-    flops = work.ops(ctx.ens, ctx.cfg, run["ex"])
-    nbytes = work.hbm_bytes(ctx.ens, ctx.cfg, ctx.features, run["ex"], run["flush_max_exit"])
+    flops = work.ops(ctx.ens, ctx.cfg, run["ex"], ctx.order)
+    nbytes = work.hbm_bytes(
+        ctx.ens, ctx.cfg, ctx.features, run["ex"], run["flush_max_exit"], ctx.order
+    )
     return 100.0 * work.least_seconds(flops, nbytes, ctx.peak) / busy

@@ -9,5 +9,5 @@ def read(ctx):
     run = ctx.run
     if ctx.peak is None or run["ex"].size == 0:
         return None
-    flops = work.ops(ctx.ens, ctx.cfg, run["ex"])
+    flops = work.ops(ctx.ens, ctx.cfg, run["ex"], ctx.order)
     return 100.0 * flops / (run["elapsed_s"] * ctx.chips * ctx.peak["flops_per_s"])

@@ -53,11 +53,12 @@ def test_tiny_cell_is_correct(kind, loop):
     assert ("latency_p50_ms" in names) == (loop == "open")
 
 
-def _faulty(fault):
-    """Wrap DeviceExecutor.run so its verdicts come out broken."""
+def _faulty(fault, executor=None):
+    """Wrap ``executor.run`` (``DeviceExecutor``'s by default) so its
+    verdicts come out broken."""
     from repro.kernels.device_executor import DeviceExecutor
 
-    inner = DeviceExecutor.run
+    inner = (executor or DeviceExecutor).run
 
     def run(self, batch, n, *a, **kw):
         res = inner(self, batch, n, *a, **kw)
@@ -116,7 +117,7 @@ def test_control_fails_where_the_reference_holds():
                      train_rows=2000, pool_rows=2000)
     ens = harness.load_ensemble(cell.config["ensemble"])
     params, plan, _ = harness.fitted_artifact(cell, ens)
-    sess = harness.Session(cell, ens, params, plan, harness._world(cell.config), False)
+    sess = harness.Session(cell, ens, params, plan, harness._world(ens, cell.config), False)
     dec, ex, amb = harness.reference_verdicts(sess)
     cdec, cex, _ = harness.reference_verdicts(sess, lower=True)
     idx = np.arange(dec.size)

@@ -31,10 +31,11 @@ def test_lattice_counts_by_hand():
 def test_work_and_least_time():
     cfg = {"depth": 5}
     ex = np.array([1, 40, 500, 3])
-    assert work.ops(oblivious_trees, cfg, ex) == 544 * 11
+    order = np.arange(500)
+    assert work.ops(oblivious_trees, cfg, ex, order) == 544 * 11
     # 4 rows of 14 float32 features in and 8 bytes out; two flushes that
     # reached models 500 and 40
-    assert work.hbm_bytes(oblivious_trees, cfg, 14, ex, [500, 40]) == 4 * 64 + 540 * 168
+    assert work.hbm_bytes(oblivious_trees, cfg, 14, ex, [500, 40], order) == 4 * 64 + 540 * 168
     peak = {"flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
     assert work.least_seconds(1000.0, 50.0, peak) == 10.0
     assert work.least_seconds(100.0, 50.0, peak) == 5.0

@@ -5,7 +5,9 @@ smooth decision function, CDF-squashed into [0, 1]), kept with the
 benchmark so that the rows it serves and checks do not depend on the
 program.  One call draws ``train_rows + pool_rows`` rows from the world
 seed: the first ``train_rows`` train the ensemble and calibrate the plan,
-the rest are the held-out pool that traffic draws requests from.
+the rest are the held-out pool that traffic draws requests from.  A kind
+that brings its own rows (``world(cfg)`` in its ensemble module) returns
+the same ``World``, possibly of integer rows.
 """
 
 from __future__ import annotations
@@ -17,9 +19,9 @@ import numpy as np
 
 @dataclasses.dataclass
 class World:
-    x_train: np.ndarray  # (train_rows, D) float32 in [0, 1]
+    x_train: np.ndarray  # (train_rows, D): here float32 in [0, 1]
     y_train: np.ndarray  # (train_rows,) int64 labels
-    pool: np.ndarray  # (pool_rows, D) float32, held out from training
+    pool: np.ndarray  # (pool_rows, D), like x_train, held out from training
 
 
 def _sigmoid(z):
