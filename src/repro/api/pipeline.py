@@ -711,7 +711,7 @@ class CompiledCascade:
         *,
         score_fn=None,
         batch_size: int = 32,
-        policy: str = "sorted-kernel",
+        policy: str | None = None,
         streaming: bool = False,
         **server_kw,
     ):
@@ -746,7 +746,7 @@ class CompiledCascade:
             executor=executor,
             batch_groups=batch_size,
             streaming=streaming,
-            policy="skip-ahead" if policy == "sorted-kernel" else policy,
+            policy="skip-ahead" if policy in (None, "sorted-kernel") else policy,
             **server_kw,
         )
 
@@ -756,7 +756,7 @@ class CompiledCascade:
         score_fn: Callable | None = None,
         chunk_score_fn: Callable | None = None,
         batch_size: int = 256,
-        policy: str = "sorted-kernel",
+        policy: str | None = None,
         audit_full_scores: bool = True,
         score_block_n: int = 1,
         streaming: bool = False,
@@ -768,7 +768,11 @@ class CompiledCascade:
 
         ``policy`` is the server's sorting/decide policy (what its own
         ``backend=`` kwarg has always named: ``cascade-scan`` | ``kernel``
-        | ``sorted-kernel``) — orthogonal to the execution backend.
+        | ``sorted-kernel``) — orthogonal to the execution backend.  By
+        default it follows the compiled scorer: ``kernel`` where the bound
+        scorer carries per-row state (its stage-0 scores cannot be computed
+        apart from that state, so there is no sort key), ``sorted-kernel``
+        for every other.
         ``score_fn`` defaults to the one captured by ``fit``; a compiled
         ``scorer=`` template becomes the server's device scorer.  The
         server builds its own executor sized to the flush capacity, so
@@ -780,7 +784,7 @@ class CompiledCascade:
         survivor-slot capacity, ``window`` the admission-ring size, and
         ``max_wait`` the partial-admission deadline in stage steps.
         Streaming admission replaces the sorting policy, so ``policy``
-        must stay the default (it is ignored in favor of ``kernel``).
+        must be left unset (streaming admits in ``kernel`` order).
 
         A grouped fit (``fit(..., groups=)``) serves QUERIES, not rows:
         the call routes to ``_serve_grouped`` and returns a
@@ -832,7 +836,7 @@ class CompiledCascade:
                     f"backend {self.backend.name!r} does not support "
                     "streaming admission; compile onto 'device' or 'sharded'"
                 )
-            if policy != "sorted-kernel":
+            if policy is not None:
                 # mirror StreamingServer's own backend= guard: streaming
                 # admission IS the ordering policy, so an explicit policy
                 # request must fail loudly, not be silently replaced
@@ -851,7 +855,18 @@ class CompiledCascade:
             raise ValueError("window/max_wait require serve(streaming=True)")
         return QWYCServer(
             self.fitted.model,
-            backend=policy,
+            backend=policy or self._default_policy(),
             **common,
             **server_kw,
         )
+
+    def _default_policy(self) -> str:
+        """``kernel`` for a stateful bound scorer, else ``sorted-kernel``."""
+        if self.scorer_template is None:
+            return "sorted-kernel"
+        bound = (
+            self.scorer
+            if self.backend.capabilities.on_device
+            else self.scorer_template.bind(DevicePlan.from_plan(self.plan))
+        )
+        return "kernel" if bound.stateful else "sorted-kernel"

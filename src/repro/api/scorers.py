@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -214,9 +215,12 @@ class NeuralScorer(StageScorer):
         state = {"h": (S_seq, d_model) residual, "s_prev": () f32}
 
     ``stage(state, t0, t0+W, ...)`` runs layers ``t0*k .. (t0+W)*k`` of
-    the scan-stacked transformer on the survivors' carried ``h`` (same
+    the transformer on the survivors' carried ``h`` (same
     ``_apply_block``, same windows/positions as ``forward``), applying
-    the exit head to the last-token state after each segment.  Attention
+    the exit head to the last-token state after each segment.  Layer
+    ``l < first_dense_layers`` runs its own dense block (DeepSeek's layer
+    0), every later one the scan-stacked block; the W positions of a
+    stage are one loop, so each layer kind is one program region.  Attention
     K/V are recomputed from the carried residual each segment —
     prefill-style classification, exact by construction, so no separate
     KV cache rides the buffers.  At ``t0 == 0`` the state is initialized
@@ -245,10 +249,11 @@ class NeuralScorer(StageScorer):
                 "NeuralScorer requires a uniform (scan-stacked) layer stack; "
                 f"layer_pattern={cfg.layer_pattern!r} is not uniform"
             )
-        if cfg.first_dense_layers:
+        n_pre = cfg.first_dense_layers
+        if n_pre and len(params.get("pre_layers", ())) != n_pre:
             raise ValueError(
-                "NeuralScorer does not support first_dense_layers > 0: every "
-                "layer must sit on the exit grid"
+                f"cfg.first_dense_layers={cfg.first_dense_layers} needs as many "
+                "'pre_layers' in params (models.transformer.init_params)"
             )
         if "exit_heads" not in params:
             raise ValueError("params must carry 'exit_heads' (cfg.exit_interval set at init)")
@@ -307,10 +312,9 @@ class NeuralScorer(StageScorer):
                 f"{plan.lead_t}); use the 'kernel' policy, not 'sorted-kernel'"
             )
 
-        layers = params["layers"]
-        heads = params["exit_heads"]
         embed = params["embed"]
-        stack_kind = cfg.layer_kinds()[0]
+        n_pre = int(cfg.first_dense_layers)
+        kinds = cfg.layer_kinds()
         win_arr = jnp.asarray(layer_windows(cfg), dtype=jnp.int32)
         positions = jnp.arange(self.seq_len, dtype=jnp.int32)
         W = dplan.W
@@ -319,6 +323,11 @@ class NeuralScorer(StageScorer):
         state_spec = {
             "h": jax.ShapeDtypeStruct((self.seq_len, d_model), dt),
             "s_prev": jax.ShapeDtypeStruct((), jnp.float32),
+        }
+        weights = {
+            "layers": params["layers"],
+            "pre": list(params.get("pre_layers", [])),
+            "heads": params["exit_heads"],
         }
 
         def prepare(tokens):
@@ -332,32 +341,54 @@ class NeuralScorer(StageScorer):
                 )
             return L.embed_tokens(embed, toks, cfg)
 
-        def _segment(h, sp, t0):
+        def _take(tree, i):
+            return jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree
+            )
+
+        def _layer(wts, h, li):
+            """Layer ``li`` (traced) on the carried residual stream: one
+            branch per leading dense layer, then the scan-stacked block."""
+
+            def stacked(h):
+                lp = _take(wts["layers"], jnp.maximum(li - n_pre, 0))
+                return _apply_block(
+                    lp, h, cfg, kinds[n_pre], positions, win_arr[li], None
+                )[0]
+
+            def dense(i, h):
+                return _apply_block(
+                    wts["pre"][i], h, cfg, kinds[i], positions, win_arr[i], None
+                )[0]
+
+            if not n_pre:
+                return stacked(h)
+            branches = [functools.partial(dense, i) for i in range(n_pre)]
+            return jax.lax.switch(jnp.minimum(li, n_pre), branches + [stacked], h)
+
+        def _segment(wts, h, sp, t0):
             """Run exits [t0, t0 + W) on the carried residual stream.
 
             ``t0`` may be traced (batch stages) or a static int (the lane
-            sweep); exits past E are valid-masked so padded columns stay
-            inert and the loop body is shape-uniform.
+            sweep); exits past E leave the stream as it is and score 0,
+            so padded columns stay inert and the loop body is uniform.
             """
-            cols = []
-            for w in range(W):
+
+            def position(w, carry):
+                h, sp, cols = carry
                 p_idx = jnp.asarray(t0, jnp.int32) + w
                 valid = p_idx < E
                 p_c = jnp.minimum(p_idx, E - 1)
-                h2 = h
-                for j in range(k):
-                    li = p_c * k + j
-                    lp = jax.tree_util.tree_map(
-                        lambda a: jax.lax.dynamic_index_in_dim(
-                            a, li, 0, keepdims=False
-                        ),
-                        layers,
-                    )
-                    h2, _, _ = _apply_block(
-                        lp, h2, cfg, stack_kind, positions, win_arr[li], None
-                    )
-                h = jnp.where(valid, h2, h)
-                head = jax.lax.dynamic_index_in_dim(heads, p_c, 0, keepdims=False)
+
+                def run(h):
+                    for j in range(k):
+                        h = _layer(wts, h, p_c * k + j)
+                    return h
+
+                h = jax.lax.cond(valid, run, lambda h: h, h)
+                head = jax.lax.dynamic_index_in_dim(
+                    wts["heads"], p_c, 0, keepdims=False
+                )
                 # same contraction as core.early_exit.exit_scores: the raw
                 # (un-normed) last-token residual against the exit head, f32
                 s = jnp.einsum(
@@ -365,21 +396,24 @@ class NeuralScorer(StageScorer):
                     h[:, -1, :].astype(jnp.float32),
                     head.astype(jnp.float32),
                 )
-                cols.append(jnp.where(valid, s - sp, 0.0))
-                sp = jnp.where(valid, s, sp)
-            return jnp.stack(cols, axis=1), h, sp
+                cols = cols.at[:, w].set(jnp.where(valid, s - sp, 0.0))
+                return h, jnp.where(valid, s, sp), cols
 
-        def stage_fn(state, t0, t1, rows, x, n_valid):
+            cols = jnp.zeros((h.shape[0], W), jnp.float32)
+            h, sp, cols = jax.lax.fori_loop(0, W, position, (h, sp, cols))
+            return cols, h, sp
+
+        def stage_fn(wts, state, t0, t1, rows, x, n_valid):
             xr = jnp.take(x, rows, axis=0)  # trash rows clamp; masked below
             first = jnp.asarray(t0, jnp.int32) == 0
             h = jnp.where(first, xr.astype(dt), state["h"])
             sp = jnp.where(first, 0.0, state["s_prev"])
-            scores, h, sp = _segment(h, sp, t0)
+            scores, h, sp = _segment(wts, h, sp, t0)
             return scores, {"h": h, "s_prev": sp}
 
         stage_starts = [int(t) for t in dplan.stage_t0]
 
-        def lane_stage_fn(state, t0_lane, rows, x, n_valid):
+        def lane_stage_fn(wts, state, t0_lane, rows, x, n_valid):
             xr = jnp.take(x, rows, axis=0)
             first = t0_lane == 0
             h = jnp.where(first[:, None, None], xr.astype(dt), state["h"])
@@ -387,7 +421,7 @@ class NeuralScorer(StageScorer):
             out = jnp.zeros((xr.shape[0], W), jnp.float32)
             h_out, sp_out = h, sp
             for q in stage_starts:
-                s_q, h_q, sp_q = _segment(h, sp, q)
+                s_q, h_q, sp_q = _segment(wts, h, sp, q)
                 sel = t0_lane == q
                 out = jnp.where(sel[:, None], s_q, out)
                 h_out = jnp.where(sel[:, None, None], h_q, h_out)
@@ -402,6 +436,7 @@ class NeuralScorer(StageScorer):
             state_spec=state_spec,
             stage_fn=stage_fn,
             lane_stage_fn=lane_stage_fn,
+            weights=weights,
         )
 
 

@@ -312,6 +312,10 @@ class BoundScorer:
     lane), so the fused path can never silently engage for them.
     ``state_spec``: pytree of ``jax.ShapeDtypeStruct`` with PER-ROW
     shapes (no capacity axis); ``()`` declares a stateless scorer.
+    ``weights``: a stateful scorer's parameters, handed to ``stage_fn``
+    and ``lane_stage_fn`` as their first argument.  DeviceExecutor passes
+    them to its programs as arguments, so a model's layers are never
+    baked into a compiled program as constants.
     ``model_partition`` (optional): the 2-D-mesh ticket (DESIGN.md §13).
     ``model_partition(model_shards) -> (mparams, col_fn)`` where
     ``mparams`` is a pytree of stage-stacked slab slices with a LEADING
@@ -335,6 +339,7 @@ class BoundScorer:
     stage_fn: Callable | None = None
     lane_stage_fn: Callable | None = None
     model_partition: Callable | None = None
+    weights: object = ()
 
     @property
     def stateful(self) -> bool:
@@ -357,13 +362,13 @@ class BoundScorer:
         """The protocol: scores for cascade positions [t0, t1) of the
         buffer's rows, plus the carried-forward state."""
         if self.stage_fn is not None:
-            return self.stage_fn(state, t0, t1, rows, x, n_valid)
+            return self.stage_fn(self.weights, state, t0, t1, rows, x, n_valid)
         return self.fn(x, rows, t0, n_valid), state
 
     def lane_stage(self, state, t0_lane, rows, x, n_valid):
         """Per-lane-stage protocol variant (streaming admission)."""
         if self.lane_stage_fn is not None:
-            return self.lane_stage_fn(state, t0_lane, rows, x, n_valid)
+            return self.lane_stage_fn(self.weights, state, t0_lane, rows, x, n_valid)
         return self.lane_fn(x, rows, t0_lane, n_valid), state
 
 
@@ -800,14 +805,35 @@ class DeviceExecutor:
         self.interpret = resolve_interpret(interpret)
         self.traces = 0
         self._compiled: set = set()  # argument signatures compiled so far
-        self._jit = jax.jit(self._program)
-        self._stream_jit = jax.jit(self._stream_program, static_argnums=(0,))
+        self._jit = jax.jit(self._weighed(self._program))
+        self._stream_jit = jax.jit(
+            self._weighed(self._stream_program), static_argnums=(0,)
+        )
         # grouped (ranking) programs: k is static — verdict extraction
         # unrolls k segment-max passes
-        self._grouped_jit = jax.jit(self._grouped_program, static_argnums=(0,))
-        self._grouped_stream_jit = jax.jit(
-            self._grouped_stream_program, static_argnums=(0, 1)
+        self._grouped_jit = jax.jit(
+            self._weighed(self._grouped_program), static_argnums=(0,)
         )
+        self._grouped_stream_jit = jax.jit(
+            self._weighed(self._grouped_stream_program), static_argnums=(0, 1)
+        )
+
+    def _weighed(self, program):
+        """``program`` taking the scorer's ``weights`` as one more, last
+        argument: while it is traced the scorer reads the traced weights,
+        so they reach the device as arguments, not as constants."""
+
+        def run(*args):
+            *args, weights = args
+            bound = self.scorer
+            self.scorer = dataclasses.replace(bound, weights=weights)
+            try:
+                return program(*args)
+            finally:
+                self.scorer = bound
+
+        run.__name__ = run.__qualname__ = program.__name__  # the program's trace name
+        return run
 
     def _bn_bill(self) -> int:
         """The kernel row-block granularity billing runs at — the
@@ -1027,7 +1053,7 @@ class DeviceExecutor:
                 rows_init = np.full(cap, cap, dtype=np.int32)
                 rows_init[:n] = rows
                 rows_init = jnp.asarray(rows_init)
-            args = (x, rows_init, n)
+            args = (x, rows_init, n, self.scorer.weights)
             compile_program(self._compiled, self._jit, *args)
             out = launch_wave("device", lambda: self._jit(*args))
         with TraceAnnotation(tracing.RUN_FETCH):
@@ -1284,7 +1310,10 @@ class DeviceExecutor:
         assert (np.diff(arr) >= 0).all(), "arrivals must be nondecreasing"
         arr_pad = np.zeros(R, dtype=np.int32)
         arr_pad[:n] = arr
-        args = (cap, x, jnp.asarray(ring_ids), jnp.asarray(arr_pad), n)
+        args = (
+            cap, x, jnp.asarray(ring_ids), jnp.asarray(arr_pad), n,
+            self.scorer.weights,
+        )
         compile_program(self._compiled, self._stream_jit, *args, static=1)
         dec, ex, gout, admit, done, s_f = launch_wave(
             "device", lambda: self._stream_jit(*args)
@@ -1510,6 +1539,7 @@ class DeviceExecutor:
             jnp.asarray(valid_init),
             n_groups,
             jnp.asarray(eps_g, dtype=jnp.float32),
+            self.scorer.weights,
         )
         compile_program(self._compiled, self._grouped_jit, *args, static=1)
         verd, exst, marg, s_f, n_f, n_in_log = launch_wave(
@@ -1757,6 +1787,7 @@ class DeviceExecutor:
             jnp.asarray(arr_pad),
             n_groups,
             jnp.asarray(eps_g, dtype=jnp.float32),
+            self.scorer.weights,
         )
         compile_program(self._compiled, self._grouped_stream_jit, *args, static=2)
         verd, exst, marg, admit, done, s_f = launch_wave(

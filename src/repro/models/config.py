@@ -34,6 +34,17 @@ class ModelConfig:
     #   'R' recurrent block (RG-LRU), 'W' RWKV6 time-mix block.
     layer_pattern: str = "G"
     rope_theta: float = 10000.0
+    # YaRN rope scaling (DeepSeek-V2): 0 = plain rope.  ``rope_factor`` is
+    # the context extension s, ``rope_original_max_pos`` the pre-extension
+    # length L0; ``rope_beta_fast``/``rope_beta_slow`` bound the blended
+    # band; ``rope_mscale``/``rope_mscale_all_dim`` set the cos/sin and
+    # softmax temperature factors (models/mla.py).
+    rope_factor: float = 0.0
+    rope_original_max_pos: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     attn_bias: bool = False
 
     # --- MLA (DeepSeek) -----------------------------------------------------
@@ -50,7 +61,11 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0  # per-expert hidden size
     first_dense_layers: int = 0  # deepseek: layer 0 keeps a dense FFN
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True  # renormalise the top-k router weights
+    # the routed experts this chip holds, by global id; () = all of them.
+    # Routing is over all ``n_experts``; a layer computes its own experts'
+    # part of the result (expert parallelism without the exchange).
+    experts_held: tuple[int, ...] = ()
     router_aux_weight: float = 0.01
 
     # --- recurrent (rwkv / rglru) --------------------------------------------
@@ -64,6 +79,7 @@ class ModelConfig:
 
     # --- misc ----------------------------------------------------------------
     tie_embeddings: bool = True
+    scale_embeddings: bool = True  # x sqrt(d_model) after the lookup (gemma)
     norm_eps: float = 1e-6
     mlp_kind: str = "swiglu"  # swiglu | gelu
     # serving-time override: cap attention window for ultra-long decode
@@ -75,6 +91,10 @@ class ModelConfig:
 
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    def held_experts(self) -> tuple[int, ...]:
+        """Global ids of the routed experts whose weights this chip holds."""
+        return tuple(self.experts_held) or tuple(range(self.n_experts))
 
     def pattern_at(self, layer: int) -> str:
         return self.layer_pattern[layer % len(self.layer_pattern)]
@@ -114,6 +134,7 @@ class ModelConfig:
             nope_head_dim=32 if self.kv_lora_rank else self.nope_head_dim,
             v_head_dim=32 if self.kv_lora_rank else self.v_head_dim,
             n_experts=min(self.n_experts, 4),
+            experts_held=(),
             n_shared_experts=min(self.n_shared_experts, 1),
             top_k=min(self.top_k, 2),
             moe_d_ff=min(self.moe_d_ff, 64) if self.moe_d_ff else 0,

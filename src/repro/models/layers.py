@@ -41,14 +41,24 @@ def softcap(x: jax.Array, cap: float) -> jax.Array:
     return cap * jnp.tanh(x / cap) if cap else x
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding.  x: (..., S, n_heads, head_dim); positions: (..., S)."""
+def rope(
+    x: jax.Array,
+    positions: jax.Array,
+    theta: float,
+    inv_freq: jax.Array | None = None,
+    mscale: float = 1.0,
+) -> jax.Array:
+    """Rotary embedding (rotate-half).  x: (..., S, n_heads, head_dim);
+    positions: (..., S).  ``inv_freq`` (head_dim // 2,) replaces the plain
+    ``theta ** (-2i / head_dim)`` frequencies and ``mscale`` scales cos and
+    sin (YaRN, ``models/mla.py``)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
-    cos = jnp.cos(angles)[..., None, :]  # (..., S, 1, half)
-    sin = jnp.sin(angles)[..., None, :]
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = positions[..., None].astype(jnp.float32) * inv_freq  # (..., S, half)
+    cos = jnp.cos(angles)[..., None, :] * mscale  # (..., S, 1, half)
+    sin = jnp.sin(angles)[..., None, :] * mscale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -221,7 +231,8 @@ def init_embed(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
 
 
 def embed_tokens(p: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
-    return jnp.take(p["tok"], tokens, axis=0) * math.sqrt(cfg.d_model)
+    x = jnp.take(p["tok"], tokens, axis=0)
+    return x * math.sqrt(cfg.d_model) if cfg.scale_embeddings else x
 
 
 def unembed(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:

@@ -9,6 +9,17 @@ from the latent at attention time.
 Cache layout: (B, S, kv_lora_rank + rope_head_dim).  For the decode path the
 up-projection is applied to the gathered latent — the structural source of
 MLA's long-context memory win, visible directly in the roofline memory term.
+
+YaRN (DeepSeek-V2, ``cfg.rope_factor`` = s > 0): with rope dim d, base b
+and pre-extension length L0, the rope frequencies blend the plain
+``extra_i = b^(-2i/d)`` with the interpolated ``inter_i = extra_i / s``
+over the band [lo, hi] of ``corr(r) = d ln(L0 / (2 pi r)) / (2 ln b)``,
+``lo = floor(corr(beta_fast))``, ``hi = ceil(corr(beta_slow))``; cos and
+sin are scaled by ``m(s, mscale) / m(s, mscale_all_dim)`` and the softmax
+by ``m(s, mscale_all_dim)^2``, where ``m(s, mu) = 0.1 mu ln s + 1``.
+Departure from the published code: it interleaves the rope dimensions
+before rotate-half; here rotate-half acts on contiguous halves, a fixed
+permutation of the rope weight columns.
 """
 
 from __future__ import annotations
@@ -25,6 +36,55 @@ from repro.models.layers import init_dense, rms_norm, rope, softcap
 Params = dict[str, Any]
 
 Q_CHUNK = 256
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """m(s, mu) = 0.1 mu ln s + 1 for s > 1, else 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_band(cfg: ModelConfig) -> tuple[int, int]:
+    """(lo, hi): the rope dimensions over which YaRN blends frequencies."""
+    d, base = cfg.rope_head_dim, cfg.rope_theta
+
+    def corr(rotations):
+        return d * math.log(cfg.rope_original_max_pos / (2 * math.pi * rotations)) / (
+            2 * math.log(base)
+        )
+
+    lo = max(math.floor(corr(cfg.rope_beta_fast)), 0)
+    hi = min(math.ceil(corr(cfg.rope_beta_slow)), d - 1)
+    return lo, hi
+
+
+def rope_inv_freq(cfg: ModelConfig) -> jax.Array | None:
+    """YaRN's blended inverse frequencies, or None for plain rope."""
+    if not cfg.rope_factor:
+        return None
+    d, base = cfg.rope_head_dim, cfg.rope_theta
+    i = jnp.arange(d // 2, dtype=jnp.float32)
+    extra = base ** (-2.0 * i / d)
+    inter = extra / cfg.rope_factor
+    lo, hi = yarn_band(cfg)
+    mask = 1.0 - jnp.clip((i - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return inter * (1.0 - mask) + extra * mask
+
+
+def rope_mscale(cfg: ModelConfig) -> float:
+    """YaRN's cos/sin factor m(s, mscale) / m(s, mscale_all_dim)."""
+    if not cfg.rope_factor:
+        return 1.0
+    return yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / yarn_mscale(
+        cfg.rope_factor, cfg.rope_mscale_all_dim
+    )
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope head dim)^-1/2, times m(s, mscale_all_dim)^2 under YaRN."""
+    scale = 1.0 / math.sqrt(cfg.nope_head_dim + cfg.rope_head_dim)
+    if cfg.rope_factor:
+        scale *= yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+    return scale
 
 
 def init_mla(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -56,11 +116,10 @@ def init_mla_cache(cfg: ModelConfig, batch: int, seq: int, dtype) -> Params:
     }
 
 
-def _mla_attend(q_n, q_r, k_n, k_r, v, q_pos, k_pos, attn_cap, q_chunk=Q_CHUNK):
+def _mla_attend(q_n, q_r, k_n, k_r, v, q_pos, k_pos, attn_cap, scale, q_chunk=Q_CHUNK):
     """Chunked attention over concatenated (nope, rope) head dims."""
     b, sq, h, dn = q_n.shape
     dr = q_r.shape[-1]
-    scale = 1.0 / math.sqrt(dn + dr)
     n_chunks = max(1, (sq + q_chunk - 1) // q_chunk)
     pad = n_chunks * q_chunk - sq
     if pad:
@@ -106,13 +165,15 @@ def apply_mla(
         q = x @ p["wq"]
     q = q.reshape(b, s, h, dn + dr)
     q_n, q_r = q[..., :dn], q[..., dn:]
-    q_r = rope(q_r, positions[None, :], cfg.rope_theta)
+    inv_freq, mscale = rope_inv_freq(cfg), rope_mscale(cfg)
+    q_r = rope(q_r, positions[None, :], cfg.rope_theta, inv_freq, mscale)
 
     lat_new = x @ p["wkv_a"]  # (B, S, r_kv + dr)
     c_kv_new = lat_new[..., :r_kv]
-    k_r_new = rope(lat_new[..., r_kv:][:, :, None, :], positions[None, :], cfg.rope_theta)[
-        :, :, 0
-    ]
+    k_r_new = rope(
+        lat_new[..., r_kv:][:, :, None, :], positions[None, :], cfg.rope_theta,
+        inv_freq, mscale,
+    )[:, :, 0]
     lat_new = jnp.concatenate([c_kv_new, k_r_new], axis=-1)
 
     if cache is None:
@@ -135,7 +196,7 @@ def apply_mla(
         # the (B, S, H, dn) no-PE keys / (B, S, H, dv) values.  Per decoded
         # token this cuts the cache-side compute from O(S*r*H*(dn+dv)) to
         # O(S*r*H) and the HBM traffic to one read of the latent itself.
-        scale = 1.0 / math.sqrt(dn + dr)
+        scale = softmax_scale(cfg)
         wk = p["wk_b"].reshape(r_kv, h, dn)
         q_abs = jnp.einsum("bshd,rhd->bshr", q_n.astype(jnp.float32),
                            wk.astype(jnp.float32))
@@ -154,6 +215,8 @@ def apply_mla(
     else:
         k_n = (c_kv @ p["wk_b"]).reshape(b, -1, h, dn)
         v = (c_kv @ p["wv_b"]).reshape(b, -1, h, dv)
-        out = _mla_attend(q_n, q_r, k_n, k_r, v, positions, k_pos, cfg.attn_softcap)
+        out = _mla_attend(
+            q_n, q_r, k_n, k_r, v, positions, k_pos, cfg.attn_softcap, softmax_scale(cfg)
+        )
     out = out.reshape(b, s, h * dv) @ p["wo"]
     return out, cache

@@ -27,6 +27,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.models import layers as L
 from repro.models import mla as MLA
 from repro.models import moe as MOE
@@ -78,7 +79,8 @@ def _apply_block(
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind in ("G", "L"):
         if cfg.kv_lora_rank:
-            mix_out, cache = MLA.apply_mla(p["attn"], h, cfg, positions, cache)
+            with jax.named_scope(tracing.MLA):
+                mix_out, cache = MLA.apply_mla(p["attn"], h, cfg, positions, cache)
         else:
             mix_out, cache = L.apply_attn(p["attn"], h, cfg, positions, window, cache)
     elif kind == "W":
@@ -91,7 +93,8 @@ def _apply_block(
     if "moe" in p:
         ffn_out, aux = MOE.apply_moe(p["moe"], h, cfg)
     else:
-        ffn_out = L.apply_mlp(p["mlp"], h, cfg)
+        with jax.named_scope(tracing.FFN):
+            ffn_out = L.apply_mlp(p["mlp"], h, cfg)
     return x + ffn_out, cache, aux
 
 
@@ -261,6 +264,7 @@ def forward(
             x = constrain(x)
             aux_total += aux
             new_pre.append(c)
+            hidden.append(x)
         stack_kind = kinds[n_pre]
         win_arr = jnp.asarray(windows[n_pre:], dtype=jnp.int32)
 
@@ -288,7 +292,8 @@ def forward(
                 (params["layers"], win_arr, None),
                 unroll=unroll,
             )
-            hidden = hs  # (L, B, S, d)
+            # (L, B, S, d): the peeled dense layers first
+            hidden = jnp.concatenate([jnp.stack(hidden), hs]) if hidden else hs
             new_cache = None
         else:
             (x, aux_total), _ = jax.lax.scan(

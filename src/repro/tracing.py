@@ -27,6 +27,9 @@ Device scopes: ``SCORE_DECIDE`` (a stage's scoring and decide kernels),
 ``COMPACT`` (the rest of a stage: row gathers, exit scatters, survivor
 repacking), ``FINALIZE`` (after the stage loop), ``SORT_KEY`` (the sort-key
 program) and ``COLLECTIVE`` (the sharded program's all-gathers and psums).
+Inside a neural stage's scoring: ``MLA``, ``FFN``, ``MOE_ROUTE``,
+``MOE_EXPERTS`` and ``MOE_SHARED`` (``models/transformer.py``,
+``models/moe.py``).
 """
 
 FLUSH = "qwyc.flush"
@@ -45,3 +48,12 @@ COMPACT = "qwyc.compact"
 FINALIZE = "qwyc.finalize"
 SORT_KEY = "qwyc.sort_key"
 COLLECTIVE = "qwyc.collective"
+# inside a neural stage: a layer's latent attention, its dense FFN, and
+# the MoE layer's routing and dispatch, held-expert kernel and shared
+# experts (``_`` and not ``.`` after ``moe``, so that a reader taking the
+# innermost ``qwyc.<name>`` keeps the three apart)
+MLA = "qwyc.mla"
+FFN = "qwyc.ffn"
+MOE_ROUTE = "qwyc.moe_route"
+MOE_EXPERTS = "qwyc.moe_experts"
+MOE_SHARED = "qwyc.moe_shared"

@@ -246,3 +246,22 @@ def test_sort_key_program_compiles(one_chip, cap):
     text = jax.jit(sort_key_program(scorer)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert re.search(r"\bsort\(", text)
+
+
+def test_expert_kernel_compiles(one_chip):
+    """The MoE layer's held-expert kernel at DeepSeek-V2-Lite's widths
+    (d_model 2048, expert width 1408, 8 experts held), bfloat16, named so
+    that a trace finds it."""
+    from repro.kernels.expert_kernel import KERNEL_NAME, TILE_M, _pallas_ffn
+
+    E, Dm, Fw, R = 8, 2048, 1408, 16 * TILE_M
+    BF = jnp.bfloat16
+    text = jax.jit(lambda x, wi, wg, wo, g: _pallas_ffn(x, wi, wg, wo, g, False)).lower(
+        jax.ShapeDtypeStruct((R, Dm), BF, sharding=one_chip),
+        jax.ShapeDtypeStruct((E, Dm, Fw), BF, sharding=one_chip),
+        jax.ShapeDtypeStruct((E, Dm, Fw), BF, sharding=one_chip),
+        jax.ShapeDtypeStruct((E, Fw, Dm), BF, sharding=one_chip),
+        jax.ShapeDtypeStruct((E,), I32, sharding=one_chip),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{KERNEL_NAME}[.\d]* = ", text)

@@ -157,7 +157,7 @@ def test_device_program_scopes(megakernel):
     cap = ex._cap(Fo.shape[0])
     x = jnp.pad(jnp.asarray(Fo), ((0, cap - Fo.shape[0]), (0, 0)))
     names = _op_names(
-        ex._jit.lower(x, jnp.arange(cap, dtype=jnp.int32), Fo.shape[0])
+        ex._jit.lower(x, jnp.arange(cap, dtype=jnp.int32), Fo.shape[0], ex.scorer.weights)
     )
     assert _has(names, r"while/body/qwyc\.compact/qwyc\.score_decide/")
     # gathers, scatters and repacking: in the stage, outside the kernels
