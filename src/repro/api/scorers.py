@@ -47,6 +47,7 @@ from repro.kernels.device_executor import (
     DEFAULT_BLOCK_N,
     BoundScorer,
     DevicePlan,
+    lane_block,
     lattice_stage_scorer,
     matrix_stage_scorer,
     tree_stage_scorer,
@@ -202,6 +203,19 @@ class FunctionScorer(StageScorer):
         return self.factory(dplan)
 
 
+#: tokens a neural stage computes at once: 8,192 fill the MXU tiles, and
+#: each held expert still gets hundreds of routed slots a block.  On a TPU
+#: v5e, stage 0 (8 layers) of DeepSeek-V2-Lite over 128 rows of 512 tokens
+#: took 0.652 s at 8,192 or 16,384 tokens a block and 0.774 s in one block.
+NEURAL_BLOCK_TOKENS = 8192
+
+
+def neural_block_rows(seq_len: int) -> int:
+    """Rows of a neural stage's lane block: the largest power of two that
+    holds at most ``NEURAL_BLOCK_TOKENS`` tokens (at least one row)."""
+    return 1 << (max(1, NEURAL_BLOCK_TOKENS // int(seq_len)).bit_length() - 1)
+
+
 class NeuralScorer(StageScorer):
     """QWYC over transformer depth: cascade position t is the exit head
     after layer ``(t + 1) * exit_interval``, and the stage score is the
@@ -227,12 +241,22 @@ class NeuralScorer(StageScorer):
     from the prepared operand (embedded tokens), which also covers
     streaming rookies admitted into recycled lanes mid-loop.
 
+    A stage computes only the live rows.  The survivors are front-packed
+    in lanes ``[0, n_valid)``, so a ``fori_loop`` with a traced trip count
+    runs the segment on the first ``ceil(n_valid / bn)`` blocks of ``bn``
+    lanes, and the lanes past them score 0.  ``bn``
+    (``neural_block_rows``) is the largest power of two of rows that
+    holds at most ``NEURAL_BLOCK_TOKENS`` tokens: 16 rows at 512 tokens.
+    Where it does not tile the lane buffer, the stage runs one block of
+    all of it (``lane_block``).  ``block_n`` is ``bn``, so the executors
+    bill the blocks the stage computes.
+
     Depth order is pinned (layer t consumes layer t-1's output):
     ``bind`` rejects plans whose order isn't ``arange`` or that use a
     lead stage (``sorted-kernel`` policy).  The lane variant used by the
     streaming executors is a masked sweep over the plan's static stage
-    starts — S_stages x the batch-stage compute, fine at host-test
-    scale; a TPU deployment would block-guard lanes by stage instead.
+    starts over every lane — S_stages x the batch-stage compute: its
+    lanes are recycled, not front-packed, so no block loop applies.
 
     No ``slabs``: the fused megakernel has no survivor-state lane, so
     the executors' auto-megakernel can never engage for this scorer
@@ -403,12 +427,36 @@ class NeuralScorer(StageScorer):
             h, sp, cols = jax.lax.fori_loop(0, W, position, (h, sp, cols))
             return cols, h, sp
 
+        bn = neural_block_rows(self.seq_len)
+
         def stage_fn(wts, state, t0, t1, rows, x, n_valid):
-            xr = jnp.take(x, rows, axis=0)  # trash rows clamp; masked below
+            cap = rows.shape[0]
+            nb = lane_block(bn, cap)
             first = jnp.asarray(t0, jnp.int32) == 0
-            h = jnp.where(first, xr.astype(dt), state["h"])
-            sp = jnp.where(first, 0.0, state["s_prev"])
-            scores, h, sp = _segment(wts, h, sp, t0)
+
+            def block(i, out):
+                # the live rows are front-packed, so blocks [0, ceil(n_valid
+                # / nb)) hold every one of them
+                scores, h_all, sp_all = out
+                r0 = i * nb
+                r = jax.lax.dynamic_slice_in_dim(rows, r0, nb)
+                xr = jnp.take(x, r, axis=0)  # trash rows clamp; masked later
+                h = jax.lax.dynamic_slice_in_dim(h_all, r0, nb)
+                sp = jax.lax.dynamic_slice_in_dim(sp_all, r0, nb)
+                h = jnp.where(first, xr.astype(dt), h)
+                sp = jnp.where(first, 0.0, sp)
+                s, h, sp = _segment(wts, h, sp, t0)
+                return (
+                    jax.lax.dynamic_update_slice_in_dim(scores, s, r0, 0),
+                    jax.lax.dynamic_update_slice_in_dim(h_all, h, r0, 0),
+                    jax.lax.dynamic_update_slice_in_dim(sp_all, sp, r0, 0),
+                )
+
+            # lanes past the live blocks score 0 and keep the state they
+            # came with, which the executors' repack drops
+            out = (jnp.zeros((cap, W), jnp.float32), state["h"], state["s_prev"])
+            n_blocks = (jnp.asarray(n_valid, jnp.int32) + nb - 1) // nb
+            scores, h, sp = jax.lax.fori_loop(0, n_blocks, block, out)
             return scores, {"h": h, "s_prev": sp}
 
         stage_starts = [int(t) for t in dplan.stage_t0]
@@ -432,7 +480,7 @@ class NeuralScorer(StageScorer):
             fn=None,
             prepare=prepare,
             width=W,
-            block_n=None,
+            block_n=bn,
             state_spec=state_spec,
             stage_fn=stage_fn,
             lane_stage_fn=lane_stage_fn,
@@ -506,8 +554,9 @@ def host_producer(scorer, plan, batch):
         if m == 0:
             return np.zeros((0, t1 - t0), dtype=np.float64)
         # the Pallas-backed scorers compute at their own block_n
-        # granularity; pad the gather like ops._bucket_rows does
-        mult = bound.block_n or 1
+        # granularity; pad the gather like ops._bucket_rows does (a
+        # stateful stage takes any lane count)
+        mult = 1 if bound.stateful else (bound.block_n or 1)
         pad = -m % mult
         rows_p = (
             np.concatenate([rows_np, np.full(pad, rows_np[0], np.int32)])

@@ -191,6 +191,7 @@ __all__ = [
     "GroupedStreamResult",
     "DeviceExecutor",
     "group_topk_rows",
+    "lane_block",
     "matrix_stage_scorer",
     "pad_rows",
     "tree_stage_scorer",
@@ -297,7 +298,10 @@ class BoundScorer:
     ``block_n``: the scorer's OWN kernel row-block size — the granularity
     its block guard really computes at, which the executor uses for
     ``scores_computed`` billing (None = exact producer; billed at the
-    executor's block size).
+    executor's block size).  A stateful scorer's ``stage_fn`` loops over
+    blocks of ``block_n`` lanes where they tile the lane buffer and runs
+    one block of the whole buffer where they do not (``lane_block``);
+    ``row_block`` gives the executors that granularity.
     ``lane_fn`` / ``lane_stage_fn``: the per-lane-stage variant for the
     streaming executors — same signature with ``t0_lane`` a (cap,) vector
     of per-lane cascade starts (admission refill mixes stage-0 rookies
@@ -358,6 +362,13 @@ class BoundScorer:
             self.state_spec,
         )
 
+    def row_block(self, default: int, cap: int) -> int:
+        """The row block a stage over ``cap`` lanes computes at, for
+        ``scores_computed``: ``block_n``, else the executor's ``default``;
+        a stateful scorer's ``lane_block`` of it."""
+        bn = self.block_n or default
+        return lane_block(bn, cap) if self.stateful else bn
+
     def stage(self, state, t0, t1, rows, x, n_valid):
         """The protocol: scores for cascade positions [t0, t1) of the
         buffer's rows, plus the carried-forward state."""
@@ -370,6 +381,13 @@ class BoundScorer:
         if self.lane_stage_fn is not None:
             return self.lane_stage_fn(self.weights, state, t0_lane, rows, x, n_valid)
         return self.lane_fn(x, rows, t0_lane, n_valid), state
+
+
+def lane_block(bn: int, cap: int) -> int:
+    """Lanes per block of a stage that loops over the front-packed live
+    prefix of a ``cap``-lane buffer in blocks of ``bn``: ``bn`` where it
+    tiles the buffer, else one block of all ``cap`` lanes."""
+    return bn if cap % bn == 0 else cap
 
 
 def repack_state(state, state_new, pack):
@@ -1061,15 +1079,16 @@ class DeviceExecutor:
             dec, ex, g, s_f, n_f, n_in_log = self._unpack(np.asarray(out), cap, n)
         with TraceAnnotation(tracing.RUN_STATS):
             stages = plan.stages
-            # bill at the SCORER's kernel block size (the granularity its
-            # block guard really computes at), not the executor's buffer block
-            bn, W = self.scorer.block_n or self.block_n, self.dplan.W
+            # bill at the SCORER's row block (the granularity its block
+            # guard, or a stateful scorer's block loop, really computes
+            # at), not the executor's buffer block
+            bn, W = self.scorer.row_block(self.block_n, cap), self.dplan.W
             chunk_stats = []
             for s in range(s_f):
                 n_in = int(n_in_log[s])
                 n_next = int(n_in_log[s + 1]) if s + 1 < s_f else n_f
-                # block-guard billing: the score kernel computed the live
-                # blocks of the W-wide slab, not the whole capacity
+                # block-guard billing: the scorer computed the live blocks
+                # of the W-wide slab, not the whole capacity
                 chunk_stats.append(
                     ChunkStat(
                         t0=stages[s][0],
@@ -1325,7 +1344,7 @@ class DeviceExecutor:
         # block-guard billing per loop step, same accounting as the batch
         # path: the live lanes are front-packed, so a guarded kernel
         # computes ceil(live / block) blocks of the W-wide slab
-        bn, W = self.scorer.block_n or self.block_n, self.dplan.W
+        bn, W = self.scorer.row_block(self.block_n, cap), self.dplan.W
         scores_computed = int(((-(-occ // bn)) * bn * W).sum())
         return StreamResult(
             decisions=np.asarray(dec)[:n].astype(bool),

@@ -304,7 +304,7 @@ class ShardedDeviceExecutor:
         eps_neg = jnp.asarray(dp.eps_neg)
         col_valid = jnp.asarray(dp.col_valid)
         lane = jnp.arange(cap_l, dtype=jnp.int32)
-        bn_bill = self.scorer.block_n or self.block_n
+        bn_bill = self.scorer.row_block(self.block_n, cap_l)
 
         def _rebalance(xbuf, state, gbuf, idbuf, n_live, counts, total):
             """All-gather the survivor buffers, repack globally (stable,
@@ -663,7 +663,7 @@ class ShardedDeviceExecutor:
                 # (shards, S), reb_log the same across shards
         with TraceAnnotation(tracing.RUN_STATS):
             stages = plan.stages
-            bn = self.scorer.block_n or self.block_n
+            bn = self.scorer.row_block(self.block_n, cap_l)
             # a model shard bills its own w_local columns; summed over the
             # model axis a stage bills w_global = M * ceil(W/M) columns —
             # the honest cost of a non-dividing split (== W at M=1)
@@ -1327,7 +1327,7 @@ class ShardedDeviceExecutor:
         # per-shard block-guard billing, reconstructed from the timeline
         # (the host knows the round-robin deal, so shard membership is
         # a function of the row id)
-        bn, W = self.scorer.block_n or self.block_n, self.dplan.W
+        bn, W = self.scorer.row_block(self.block_n, cap_l), self.dplan.W
         per_shard_occ = np.zeros((shards, steps_run), dtype=np.int64)
         scores_computed = 0
         for k in range(shards):

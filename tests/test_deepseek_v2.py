@@ -219,3 +219,45 @@ def test_serve_picks_the_policy_from_the_scorer(model):
     # an explicit policy still wins
     assert fitted.compile("device", scorer=scorer).serve(
         batch_size=8, policy="cascade-scan").backend == "cascade-scan"
+
+
+def _served_with_blocks(params, fitted, rows, tokens_per_block, monkeypatch):
+    """``rows`` through ``compile("device")`` with ``tokens_per_block``
+    tokens a neural block: (the evaluated batch, the served stats, the
+    bound block size)."""
+    from repro.api import scorers
+
+    monkeypatch.setattr(scorers, "NEURAL_BLOCK_TOKENS", tokens_per_block)
+    scorer = api.NeuralScorer(params, TINY, seq_len=SEQ)
+    comp = fitted.compile("device", scorer=scorer, chunk_t=1)
+    res = comp.evaluate(x=rows)
+    srv = comp.serve(batch_size=32)
+    for r in rows:
+        srv.submit(r)
+    srv.flush()
+    srv.drain()
+    return res, srv.stats, scorers.neural_block_rows(SEQ)
+
+
+def test_neural_stages_bill_the_blocks_they_compute(model, monkeypatch):
+    """A fit with exits, served with 4-row blocks: each stage bills
+    ``ceil(n_in / bn) x bn x W``, less than the eager matrix, and the
+    verdicts and exit steps are those of one block over all lanes."""
+    params, _, _ = model
+    rng = np.random.default_rng(1)
+    calib = rng.integers(0, 256, size=(96, SEQ)).astype(np.int32)
+    rows = rng.integers(0, 256, size=(40, SEQ)).astype(np.int32)
+    s_cal = ref.exit_scores(params, TINY, calib)
+    fitted = api.fit(np.diff(s_cal, axis=1, prepend=0.0), beta=float(np.median(s_cal[:, -1])),
+                     alpha=0.05, mode="both", **api.NeuralScorer(params, TINY, SEQ).fit_overrides())
+    res, stats, bn = _served_with_blocks(params, fitted, rows, 4 * SEQ, monkeypatch)
+    one, _, whole = _served_with_blocks(params, fitted, rows, 1 << 20, monkeypatch)
+    assert bn == 4 and whole >= 64  # the 40 rows' buffer is 64 lanes
+    assert (res.exit_step < TINY.n_layers).any()  # some rows exit early
+    W = 1  # chunk_t=1: one position a stage
+    want = [-(-c.n_in // bn) * bn * W for c in res.chunk_stats]
+    assert [c.scores_computed for c in res.chunk_stats] == want
+    assert res.scores_computed == sum(want) < res.scores_possible
+    assert stats.compute_fraction < 1.0
+    np.testing.assert_array_equal(res.decisions, one.decisions)
+    np.testing.assert_array_equal(res.exit_step, one.exit_step)

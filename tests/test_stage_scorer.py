@@ -214,6 +214,49 @@ def test_neural_margin_inf_is_full_depth_forward():
     assert dex.traces == 1
 
 
+# ------------------------------------------------- neural block loop
+
+
+_BLOCK_TOKENS = 32  # 4 rows of the fixture's 8 tokens a block
+_CAP = 16  # four such blocks
+
+
+def _neural_stage_outputs(monkeypatch, tokens_per_block, n_valid, t0):
+    """One neural stage over a ``_CAP``-lane buffer, its state carried in
+    from random values: (scores, h, s_prev) and the block size bound."""
+    monkeypatch.setattr(api.scorers, "NEURAL_BLOCK_TOKENS", tokens_per_block)
+    scorer, F, x, m, plan, dplan = _fit_plan("neural")
+    bound = scorer.bind(dplan)
+    xp = bound.prepare(x[:_CAP])
+    rng = np.random.default_rng(3)
+    state = {
+        "h": jnp.asarray(rng.normal(size=xp.shape), xp.dtype),
+        "s_prev": jnp.asarray(rng.normal(size=_CAP), jnp.float32),
+    }
+    rows = jnp.asarray(rng.permutation(_CAP), jnp.int32)
+    scores, out = bound.stage(
+        state, jnp.int32(t0), jnp.int32(t0) + dplan.W, rows, xp, jnp.int32(n_valid)
+    )
+    return np.asarray(scores), np.asarray(out["h"]), np.asarray(out["s_prev"]), bound.block_n
+
+
+@pytest.mark.parametrize("n_valid", [1, 3, 4, 5, _CAP])
+def test_neural_block_loop_computes_the_live_lanes_as_one_block(monkeypatch, n_valid):
+    """The block-looped stage gives the live lanes the scores and carried
+    state of one whole-capacity block, to float32 rounding, at the first
+    stage (state from the embedded tokens) and a later one (carried);
+    lanes past the live blocks score 0."""
+    for t0 in (0, 2):
+        blocked = _neural_stage_outputs(monkeypatch, _BLOCK_TOKENS, n_valid, t0)
+        whole = _neural_stage_outputs(monkeypatch, 1 << 20, n_valid, t0)
+        bn = blocked[3]
+        assert bn == 4 and whole[3] >= _CAP
+        for got, want in zip(blocked[:3], whole[:3]):
+            np.testing.assert_allclose(got[:n_valid], want[:n_valid], rtol=1e-5, atol=1e-5)
+        live = -(-n_valid // bn) * bn
+        np.testing.assert_array_equal(blocked[0][live:], 0.0)
+
+
 # ------------------------------------------------- survivor-state pytree
 
 
